@@ -19,6 +19,8 @@ import os
 import time
 import traceback
 
+from repro import compile_cache
+
 from benchmarks import (bench_dynamics, bench_fleet, bench_planner,
                         bench_round, bench_rt, bench_scale, bench_simfleet,
                         fig5_training, fig6_cluster_size, fig7_cut_layer,
@@ -66,6 +68,7 @@ def main():
     args = ap.parse_args()
     if args.artifact and not args.stamp:
         ap.error("--artifact requires --stamp (no ambient-clock stamping)")
+    compile_cache.enable()
     quick = not args.full
     names = [args.only] if args.only else list(BENCHES)
     failures = []

@@ -10,6 +10,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro import compile_cache
 from repro.configs import registry
 from repro.configs.base import CPSLConfig
 from repro.core.channel import NetworkCfg
@@ -66,4 +67,5 @@ def main():
 
 
 if __name__ == "__main__":
+    compile_cache.enable()
     main()

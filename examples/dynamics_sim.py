@@ -17,6 +17,7 @@ import json
 import jax
 import numpy as np
 
+from repro import compile_cache
 from repro.configs.base import CPSLConfig, SimCfg
 from repro.core.channel import NetworkCfg
 from repro.core.profile import lenet_profile
@@ -79,4 +80,5 @@ def main():
 
 
 if __name__ == "__main__":
+    compile_cache.enable()
     main()

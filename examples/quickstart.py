@@ -10,9 +10,12 @@ whole training curves compiled once and executed as one batched program
 
     PYTHONPATH=src python examples/quickstart.py
 """
+import shutil
+
 import jax
 import numpy as np
 
+from repro import compile_cache
 from repro.configs.base import CPSLConfig, FleetConfig
 from repro.core.channel import NetworkCfg
 from repro.core.cpsl import CPSL
@@ -43,8 +46,9 @@ def main():
     ccfg = CPSLConfig(cut_layer=v_star, n_clusters=6, cluster_size=5,
                       local_epochs=1)
     cpsl = CPSL(make_split_model("lenet", v_star), ccfg)
-    tcfg = TrainerCfg(rounds=8, ckpt_every=4,
-                      ckpt_dir="/tmp/repro_quickstart",
+    ckpt_dir = "experiments/quickstart/ckpt"
+    shutil.rmtree(ckpt_dir, ignore_errors=True)   # a fresh run, not a resume
+    tcfg = TrainerCfg(rounds=8, ckpt_every=4, ckpt_dir=ckpt_dir,
                       resource_mgmt="gibbs", gibbs_iters=80)
 
     def eval_fn(cp, state):
@@ -80,4 +84,5 @@ def main():
 
 
 if __name__ == "__main__":
+    compile_cache.enable()
     main()

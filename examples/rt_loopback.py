@@ -30,6 +30,7 @@ import argparse
 import json
 import os
 
+from repro import compile_cache
 from repro.rt.crossval import crossval_report
 from repro.rt.faults import FaultRule, chaos_schedule
 from repro.rt.orchestrator import (RTConfig, loopback_reference,
@@ -37,20 +38,26 @@ from repro.rt.orchestrator import (RTConfig, loopback_reference,
 from repro.rt.protocol import MsgType
 
 
-def main():
-    out_dir = os.environ.get("RT_OUT_DIR", "/tmp/rt_example")
-    os.makedirs(out_dir, exist_ok=True)
-    trace = os.path.join(out_dir, "trace.jsonl")
-
-    cfg = RTConfig(
+def demo_config(trace_path: str, seed: int = 0) -> RTConfig:
+    """The demo deployment: 4 workers in 2 clusters, scaled eq. 15-25
+    delays injected, and device 3 (cluster 1, slot 1) losing its
+    round-1 model upload."""
+    return RTConfig(
         n_devices=4, cluster_size=2, rounds=3, local_epochs=1, batch=8,
-        n_train=600, n_test=64, samples_per_device=80, seed=0,
+        n_train=600, n_test=64, samples_per_device=80, seed=seed,
         delay_scale=0.05,              # inject scaled eq. 15-25 delays
         phase_timeout_s=6.0, rpc_timeout_s=1.0, retries=2, backoff_s=0.2,
         # chaos: device 3 never delivers its round-1 model upload
         faults={3: [FaultRule("drop", msg_types=(int(MsgType.AGG),),
                               rounds=(1,))]},
-        trace_path=trace)
+        trace_path=trace_path)
+
+
+def main():
+    out_dir = os.environ.get("RT_OUT_DIR", "/tmp/rt_example")
+    os.makedirs(out_dir, exist_ok=True)
+    trace = os.path.join(out_dir, "trace.jsonl")
+    cfg = demo_config(trace)
 
     print(f"spawning {cfg.n_devices} device workers "
           f"({cfg.n_clusters} clusters x {cfg.cluster_size})...")
@@ -135,6 +142,7 @@ def main_chaos():
 
 
 if __name__ == "__main__":
+    compile_cache.enable()
     ap = argparse.ArgumentParser()
     ap.add_argument("--chaos", action="store_true",
                     help="elastic-recovery drill: seeded SIGKILLs + "

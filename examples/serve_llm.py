@@ -10,6 +10,7 @@ import time
 import jax
 import jax.numpy as jnp
 
+from repro import compile_cache
 from repro.configs import registry
 from repro.models import api
 from repro.serving.engine import ServeEngine
@@ -35,4 +36,5 @@ def main():
 
 
 if __name__ == "__main__":
+    compile_cache.enable()
     main()

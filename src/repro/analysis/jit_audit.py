@@ -15,7 +15,7 @@ never executed — the audit costs trace time, not compile time):
   JIT003  implicit f64<->f32 ``convert_element_type`` beyond the
           target's documented allowance (0 everywhere today: the CPSL
           programs are pure f32, the fleet cost engine pure f64 under
-          ``enable_x64`` with inputs cast at the host boundary).
+          ``jax.enable_x64(True)`` with inputs cast at the host boundary).
   JIT004  a weak-typed aval in a ``scan``/``while`` carry — a python
           scalar leaked into carried state, which retraces the program
           whenever a caller passes a strongly-typed value (see
@@ -27,7 +27,7 @@ Audited targets (the acceptance set):
   * ``CPSL._run_training_fused``   — the R-round curve;
   * ``CPSL._run_fleet``            — E vmapped curves;
   * ``SimFleetRunner._sim``        — the two-timescale Monte-Carlo
-    simulator (traced under ``enable_x64``, its contract dtype).
+    simulator (traced under ``jax.enable_x64(True)``, its contract dtype).
 
 Also exported: the shared recompile-guard helpers ``cache_size`` and
 ``CompileCounter`` (used by ``benchmarks/bench_fleet.py`` instead of
@@ -282,7 +282,7 @@ def _audit_fleet() -> List[Finding]:
 
 
 def _audit_fleet_sim() -> List[Finding]:
-    from jax.experimental import enable_x64
+    import jax
     from repro.configs.base import SimFleetCfg
     from repro.core.channel import NetworkCfg
     from repro.core.profile import lenet_profile
@@ -295,7 +295,7 @@ def _audit_fleet_sim() -> List[Finding]:
                        policies=("equal", "greedy"), cluster_sizes=(3,),
                        cuts=(2, 3), batch_per_device=16, local_epochs=1)
     runner = SimFleetRunner(lenet_profile(), ncfg, dcfg, fcfg)
-    with enable_x64():                # the cost model's contract dtype
+    with jax.enable_x64(True):        # the cost model's contract dtype
         traced = runner._sim.trace(runner.sim_inputs())
         lowered = traced.lower()
     # _sim donates nothing (pure Monte-Carlo pricing); its contract is

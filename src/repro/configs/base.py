@@ -173,7 +173,8 @@ class CPSLConfig:
                                      # fastest solo) | "im2col" (matmul form —
                                      # batches cleanly under vmap over client/
                                      # replica weights and stays fast inside
-                                     # scans; forward bit-identical, tested).
+                                     # scans; forward equal up to summation
+                                     # order, tested).
                                      # Consumed at split-model build time
                                      # (make_split_model("lenet", v,
                                      # conv_impl=...))

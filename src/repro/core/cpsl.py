@@ -57,25 +57,6 @@ from repro.core import partitioning as pt
 from repro.core.splitting import SplitModel
 
 
-def _register_barrier_batching():
-    """jax 0.4.x has no batching rule for ``optimization_barrier`` (added
-    upstream later as the identity rule below); the fleet path vmaps the
-    fused round — which pins its program boundaries with barriers — over
-    the replica axis, so register the trivial rule when missing."""
-    try:
-        from jax._src.lax import lax as _lax_internal
-        from jax.interpreters import batching as _batching
-    except ImportError:        # pragma: no cover - future jax layouts
-        return
-    prim = getattr(_lax_internal, "optimization_barrier_p", None)
-    if prim is not None and prim not in _batching.primitive_batchers:
-        _batching.primitive_batchers[prim] = (
-            lambda args, dims, **params: (prim.bind(*args, **params), dims))
-
-
-_register_barrier_batching()
-
-
 def _flat(tree):
     return jax.tree.map(lambda t: t.reshape((-1,) + t.shape[2:]), tree)
 

@@ -119,7 +119,7 @@ class PartitionBatch:
     expression keeps the operand order of ``cluster_latency``, all in
     float64 — per-cluster latencies are bit-identical to scalar calls, and
     totals accumulate clusters left-to-right so they are bit-identical to
-    the Python ``sum`` in ``round_latency`` and
+    the left-to-right totals of ``round_latency`` and
     ``core.resource._round_latency_cached``. The multichain planner in
     ``repro.sim.batched`` relies on this to keep chain 0 of its lockstep
     Gibbs replicas bit-exact to the looped single-chain path."""
@@ -182,7 +182,7 @@ class PartitionBatch:
 
     def latencies(self, xs: np.ndarray) -> np.ndarray:
         """(R, N) allocations -> (R,) round totals, summed left-to-right
-        over clusters (bit-identical to Python ``sum``, eq. 25)."""
+        over clusters (bit-identical to ``round_latency``, eq. 25)."""
         per = self.cluster_latencies(xs)
         total = per[:, 0].copy()
         for m in range(1, self.M):
@@ -242,10 +242,15 @@ def round_latency(v: int, clusters: Sequence[Sequence[int]],
                   xs: Sequence[np.ndarray], net: NetworkState,
                   ncfg: NetworkCfg, prof: CutProfile, B: int, L: int,
                   physical_gradients: bool = False) -> float:
-    """One-round latency D^t = sum_m D_m (eq. 25)."""
-    return sum(cluster_latency(v, ds, x, net, ncfg, prof, B, L,
-                               physical_gradients)
-               for ds, x in zip(clusters, xs))
+    """One-round latency D^t = sum_m D_m (eq. 25), accumulated left to
+    right — the order every batched evaluator reproduces bit for bit.
+    (Python's ``sum`` of floats is compensated since 3.12, so it is not
+    that order.)"""
+    total = 0.0
+    for ds, x in zip(clusters, xs):
+        total += cluster_latency(v, ds, x, net, ncfg, prof, B, L,
+                                 physical_gradients)
+    return total
 
 
 def equal_split_curve(v: int, clusters: Sequence[Sequence[int]],
@@ -369,9 +374,8 @@ def _cluster_latency_j(cst, fd, rd, xs, mask, csize, *, B: int, L: int,
 
 
 def _sum_left_to_right(per_cluster):
-    """(..., M) -> (...,) accumulated m = 0, 1, ... exactly like the
-    Python ``sum`` in ``round_latency`` (padded clusters add exact 0.0,
-    a bitwise no-op)."""
+    """(..., M) -> (...,) accumulated m = 0, 1, ... exactly like
+    ``round_latency`` (padded clusters add exact 0.0, a bitwise no-op)."""
     total = per_cluster[..., 0]
     for m in range(1, per_cluster.shape[-1]):
         total = total + per_cluster[..., m]
@@ -407,8 +411,8 @@ class PartitionBatchJ:
                  device_idx: np.ndarray, net_rows=None,
                  physical_gradients: bool = False,
                  dtype=np.float64, chunk_size: int | None = None):
+        import jax
         import jax.numpy as jnp
-        from jax.experimental import enable_x64
 
         sizes = np.asarray(sizes, dtype=np.int64)
         dev = np.asarray(device_idx, dtype=np.int64)
@@ -439,7 +443,7 @@ class PartitionBatchJ:
             rows = np.asarray(net_rows, dtype=np.int64)[:, None]
             fd, rd = f_all[rows, dev], r_all[rows, dev]
 
-        with enable_x64():
+        with jax.enable_x64(True):
             # (R?, M, Kmax) padded views + static slot masks
             self._mask = jnp.asarray(self._to_slots(
                 np.ones((1, self.N)), fill=0.0) > 0.5)[0]
@@ -505,10 +509,10 @@ class PartitionBatchJ:
 
     def cluster_latencies(self, xs: np.ndarray) -> np.ndarray:
         """(R, N) allocations -> (R, M) per-cluster latencies D_m."""
-        from jax.experimental import enable_x64
+        import jax
         import jax.numpy as jnp
 
-        with enable_x64():
+        with jax.enable_x64(True):
             x = jnp.asarray(self._to_slots(np.asarray(xs, np.float64),
                                            fill=1.0).astype(self.dtype))
             if self.chunk_size:

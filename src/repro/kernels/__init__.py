@@ -1,3 +1,16 @@
-# OPTIONAL layer. Add <name>.py (or .cu) + ops.py + ref.py ONLY
-# for compute hot-spots the paper itself optimizes with a custom
-# kernel. Leave this package empty if the paper has none.
+"""Pallas TPU kernels (flash attention, Mamba-2 SSD), each with a jnp
+oracle (``ref.py``) and a jit-ready wrapper (``ops.py``)."""
+import jax
+
+
+def interpret() -> bool:
+    """Whether the wrappers run their kernel in Pallas interpret mode:
+    on the CPU (the tests' platform) they do, on the TPU they compile.
+    Any other platform has no kernel and raises — interpret mode is never
+    a silent fallback for an accelerator."""
+    platform = jax.default_backend()
+    if platform == "cpu":
+        return True
+    if platform == "tpu":
+        return False
+    raise RuntimeError(f"no Pallas kernel for platform {platform!r}")

@@ -1,6 +1,6 @@
 """Jit-ready wrapper: grouped-layout flash attention with custom VJP.
 
-Forward = Pallas kernel (interpret mode off-TPU); backward re-computes
+Forward = Pallas kernel (interpret mode on the CPU); backward re-computes
 through the chunked-jnp reference (identical math, flash-style memory) —
 the standard recompute-in-backward flash pattern without hand-writing the
 dq/dk/dv kernels.
@@ -12,11 +12,8 @@ import functools
 import jax
 import jax.numpy as jnp
 
+from repro import kernels
 from repro.kernels.flash_attention.kernel import flash_attention_flat
-
-
-def _interpret() -> bool:
-    return jax.default_backend() != "tpu"
 
 
 @functools.partial(jax.custom_vjp,
@@ -31,7 +28,7 @@ def flash_attention(q, k, v, causal=True, window=0, softcap=0.0,
     vf = v.transpose(0, 2, 1, 3).reshape(B * G, Skv, D)
     of = flash_attention_flat(qf, kf, vf, causal=causal, window=window,
                               softcap=softcap, q_offset=q_offset,
-                              kv_repeat=R, interpret=_interpret())
+                              kv_repeat=R, interpret=kernels.interpret())
     return of.reshape(B, G, R, Sq, D).transpose(0, 3, 1, 2, 4)
 
 

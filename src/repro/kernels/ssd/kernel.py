@@ -10,7 +10,9 @@ Per chunk (length Q):
                matmuls; Q defaults to 128 for full systolic tiles,
   inter-chunk: y += exp(cum) * (C h_prev);  h = exp(cum_Q) h_prev + B^T(dt x)
 
-Layout is flat per-head: x (BH, S, P), dt (BH, S), A (BH, 1), B/C (BH, S, N).
+Layout is flat per-head: x (BH, S, P), dt (BH, S, 1), A (BH, 1, 1),
+B/C (BH, S, N) — dt and A carry a trailing unit lane dim so that every
+block's last two dims are (8k, full) or (full, full), as Mosaic requires.
 The (N, P) state tile (128x64 for mamba2-2.7b) stays resident in VMEM for
 the whole sequence — the core TPU adaptation vs. the CUDA SSD kernel, which
 re-materializes state through shared memory per block.
@@ -34,34 +36,42 @@ def _ssd_kernel(x_ref, dt_ref, a_ref, b_ref, c_ref, y_ref, hT_ref, h_sc, *,
         h_sc[...] = jnp.zeros_like(h_sc)
 
     x = x_ref[0].astype(jnp.float32)           # (Q, P)
-    dt = dt_ref[0].astype(jnp.float32)         # (Q,)
-    A = a_ref[0, 0].astype(jnp.float32)        # scalar
+    dt = dt_ref[0].astype(jnp.float32)         # (Q, 1) column
+    A = a_ref[0].astype(jnp.float32)           # (1, 1)
     B = b_ref[0].astype(jnp.float32)           # (Q, N)
     C = c_ref[0].astype(jnp.float32)           # (Q, N)
 
-    dA = dt * A                                # (Q,) <= 0
-    cum = jnp.cumsum(dA)                       # inclusive
+    # Mosaic has no cumsum and no (Q, 1) <-> (1, Q) relayout, so the
+    # inclusive prefix sum and the row views come from masked reductions
+    # over (Q, Q) tiles (exact f32 sums of the same terms).
+    ii = jax.lax.broadcasted_iota(jnp.int32, (Q, Q), 0)
+    jj = jax.lax.broadcasted_iota(jnp.int32, (Q, Q), 1)
+    dA = dt * A                                # (Q, 1) <= 0
+    dA_sq = jnp.broadcast_to(dA, (Q, Q))       # [i, j] = dA[i]
+    cum_row = jnp.sum(jnp.where(ii <= jj, dA_sq, 0.0), axis=0,
+                      keepdims=True)           # (1, Q) inclusive cumsum
+    cum = jnp.sum(jnp.where(ii == jj, jnp.broadcast_to(cum_row, (Q, Q)),
+                            0.0), axis=1, keepdims=True)      # (Q, 1)
+    dt_row = jnp.sum(jnp.where(ii == jj, jnp.broadcast_to(dt, (Q, Q)),
+                               0.0), axis=0, keepdims=True)   # (1, Q)
+    total = jnp.sum(dA, axis=0, keepdims=True)                # (1, 1)
     # intra-chunk
     scores = jax.lax.dot_general(C, B, (((1,), (1,)), ((), ())),
                                  preferred_element_type=jnp.float32)
-    decay = jnp.exp(cum[:, None] - cum[None, :])
-    ii = jax.lax.broadcasted_iota(jnp.int32, (Q, Q), 0)
-    jj = jax.lax.broadcasted_iota(jnp.int32, (Q, Q), 1)
-    decay = jnp.where(ii >= jj, decay, 0.0)
-    M = scores * decay * dt[None, :]
+    decay = jnp.where(ii >= jj, jnp.exp(cum - cum_row), 0.0)
+    M = scores * decay * dt_row
     y = jax.lax.dot_general(M, x, (((1,), (0,)), ((), ())),
                             preferred_element_type=jnp.float32)
     # inter-chunk: contribution of the carried state
     h = h_sc[...]                              # (N, P)
     Ch = jax.lax.dot_general(C, h, (((1,), (0,)), ((), ())),
                              preferred_element_type=jnp.float32)
-    y = y + jnp.exp(cum)[:, None] * Ch
+    y = y + jnp.exp(cum) * Ch
     # state update
-    sdecay = jnp.exp(cum[-1] - cum) * dt       # (Q,)
-    Bw = B * sdecay[:, None]                   # (Q, N)
+    Bw = B * (jnp.exp(total - cum) * dt)       # (Q, N)
     dh = jax.lax.dot_general(Bw, x, (((0,), (0,)), ((), ())),
                              preferred_element_type=jnp.float32)
-    h_sc[...] = jnp.exp(cum[-1]) * h + dh
+    h_sc[...] = jnp.exp(total) * h + dh
     y_ref[0] = y.astype(y_ref.dtype)
 
     @pl.when(ci == n_chunks - 1)
@@ -86,8 +96,8 @@ def ssd_flat(x, dt, A, Bm, Cm, *, chunk: int = 128,
         grid=grid,
         in_specs=[
             pl.BlockSpec((1, Q, P), lambda b, ci: (b, ci, 0)),
-            pl.BlockSpec((1, Q), lambda b, ci: (b, ci)),
-            pl.BlockSpec((1, 1), lambda b, ci: (b, 0)),
+            pl.BlockSpec((1, Q, 1), lambda b, ci: (b, ci, 0)),
+            pl.BlockSpec((1, 1, 1), lambda b, ci: (b, 0, 0)),
             pl.BlockSpec((1, Q, N), lambda b, ci: (b, ci, 0)),
             pl.BlockSpec((1, Q, N), lambda b, ci: (b, ci, 0)),
         ],
@@ -101,5 +111,5 @@ def ssd_flat(x, dt, A, Bm, Cm, *, chunk: int = 128,
         ],
         scratch_shapes=[pltpu.VMEM((N, P), jnp.float32)],
         interpret=interpret,
-    )(x, dt, A[:, None], Bm, Cm)
+    )(x, dt[:, :, None], A[:, None, None], Bm, Cm)
     return y, hT

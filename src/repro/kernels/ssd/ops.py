@@ -7,11 +7,8 @@ import functools
 import jax
 import jax.numpy as jnp
 
+from repro import kernels
 from repro.kernels.ssd.kernel import ssd_flat
-
-
-def _interpret() -> bool:
-    return jax.default_backend() != "tpu"
 
 
 def _to_flat(x, dt, A, Bm, Cm):
@@ -34,7 +31,7 @@ def ssd(x, dt, A, Bm, Cm, chunk: int = 128, h0=None):
     B_, S, H, P = x.shape
     xf, dtf, Af, Bf, Cf = _to_flat(x, dt, A, Bm, Cm)
     y, hT = ssd_flat(xf, dtf, Af, Bf, Cf, chunk=chunk,
-                     interpret=_interpret())
+                     interpret=kernels.interpret())
     y = y.reshape(B_, H, S, P).transpose(0, 2, 1, 3)
     hT = hT.reshape(B_, H, *hT.shape[1:])
     return y, hT

@@ -3,19 +3,23 @@
 End-to-end: synthetic non-IID data -> resource-managed CPSL rounds with
 checkpoints and the wireless-latency simulator.
 
-    PYTHONPATH=src python -m repro.launch.train --model lenet --rounds 20
+    PYTHONPATH=src python -m repro.launch.train --model lenet --rounds 20 \
+        --ckpt-dir experiments/lenet/ckpt
     PYTHONPATH=src python -m repro.launch.train --arch qwen2-0.5b \
-        --reduced --rounds 3 --clusters 2 --cluster-size 2
+        --reduced --rounds 3 --clusters 2 --cluster-size 2 \
+        --ckpt-dir experiments/qwen2/ckpt
+
+``--ckpt-dir`` is required: the trainer resumes from whatever checkpoint
+that directory holds, so no two runs share one by default.
 """
 from __future__ import annotations
 
 import argparse
 import json
 
-import jax
 import numpy as np
 
-from repro import streams
+from repro import compile_cache, streams
 from repro.configs import registry
 from repro.configs.base import CPSLConfig
 from repro.core.channel import NetworkCfg
@@ -29,7 +33,7 @@ from repro.models import lenet
 from repro.train.trainer import CPSLTrainer, TrainerCfg
 
 
-def main():
+def parse_args(argv=None):
     ap = argparse.ArgumentParser()
     ap.add_argument("--model", default="lenet")
     ap.add_argument("--arch", default=None, help="LM arch id (see registry)")
@@ -46,11 +50,15 @@ def main():
                     help="select the cut layer with Alg. 2 (SAA)")
     ap.add_argument("--resource", default="gibbs",
                     choices=["gibbs", "random", "heuristic", "fixed"])
-    ap.add_argument("--ckpt-dir", default="/tmp/repro_train_ckpt")
+    ap.add_argument("--ckpt-dir", required=True,
+                    help="checkpoint directory; a run resumes from it")
     ap.add_argument("--log", default=None)
     ap.add_argument("--seed", type=int, default=0)
-    args = ap.parse_args()
+    return ap.parse_args(argv)
 
+
+def build(args):
+    """The trainer ``args`` describe, and its cut layer."""
     n_devices = args.clusters * args.cluster_size
     ncfg = NetworkCfg(n_devices=n_devices)
 
@@ -88,7 +96,13 @@ def main():
     tcfg = TrainerCfg(rounds=args.rounds, ckpt_dir=args.ckpt_dir,
                       resource_mgmt=args.resource, log_path=args.log,
                       seed=args.seed)
-    trainer = CPSLTrainer(CPSL(split, ccfg), ds, prof, ncfg, tcfg)
+    return CPSLTrainer(CPSL(split, ccfg), ds, prof, ncfg, tcfg), cut
+
+
+def main(argv=None):
+    args = parse_args(argv)
+    compile_cache.enable()
+    trainer, cut = build(args)
     trainer.run(streams.model_key(args.seed), v=cut)
     for h in trainer.history:
         print(json.dumps(h))

@@ -85,8 +85,9 @@ def conv_im2col(x, w, b, pad):
     """3x3 conv as im2col + matmul: 9 shifted slices concatenated into
     patch rows, one dot against the flattened kernel.
 
-    Forward is bit-identical to ``lax.conv_general_dilated`` on XLA:CPU
-    (asserted in tests); the point is the *batched* lowering: under
+    Forward equals ``lax.conv_general_dilated`` up to summation order
+    (within 4 eps of sum |x||w| on XLA:CPU, asserted in tests); the
+    point is the *batched* lowering: under
     ``jax.vmap`` over per-client / per-replica WEIGHTS a direct conv
     becomes a grouped convolution (XLA:CPU naive emitter, ~10x slower —
     see ``CPSLConfig.unroll_clients``), while this form becomes a

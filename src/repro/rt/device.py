@@ -21,6 +21,12 @@ The numerics are the *decomposed* protocol-step jits — device forward
 reproduce the monolithic ``CPSL._protocol_step`` bit-exactly on XLA:CPU
 (pinned by tests/test_rt_loopback.py).
 
+Placement: a worker always runs JAX on the CPU. ``device_main`` pins
+the platform before any backend exists, so a worker never opens the
+accelerator that the server process holds (one process per chip; edge
+devices on the CPU, the edge server on the accelerator). READY reports
+the worker's platform to the server.
+
 Robustness: an ERROR reply (server dropped us as a straggler) or a new
 CLUSTER_START mid-RPC aborts the current cluster and returns to the
 main loop; SIGTERM (``repro.lifecycle.GracefulStop``) finishes the
@@ -156,6 +162,11 @@ class DeviceWorker:
             g0 = jnp.zeros(split.smashed_spec(self.B).shape, jnp.float32)
             jax.block_until_ready(
                 self._bwd(p0, dev_opt.init(p0), np.int32(0), batch, g0))
+
+    def _send_ready(self):
+        import jax
+        self.ch.send(MsgType.READY, {"device": self.gid,
+                                     "platform": jax.default_backend()})
 
     def _start_heartbeat(self):
         interval = self.cfg.get("heartbeat_s", 0.5)
@@ -326,7 +337,7 @@ class DeviceWorker:
                 self._build(msg)       # server asked for a full rebuild
             elif mtype != MsgType.REJOIN_ACK:
                 return False
-            self.ch.send(MsgType.READY, {"device": self.gid})
+            self._send_ready()
         except (pr.ProtocolError, RpcTimeout, OSError):
             return False
         self._start_heartbeat()
@@ -335,7 +346,7 @@ class DeviceWorker:
     def run(self):
         plan = self._connect_and_plan()
         self._build(plan)
-        self.ch.send(MsgType.READY, {"device": self.gid})
+        self._send_ready()
         self._start_heartbeat()
         try:
             while True:
@@ -356,6 +367,20 @@ class DeviceWorker:
                 pass
 
 
+def pin_to_cpu():
+    """Make the CPU this process's only JAX platform. JAX fixes its
+    platforms when the first backend starts, so this must run before
+    anything touches a backend — raises otherwise, rather than leave the
+    worker free to open the accelerator."""
+    import jax
+    from jax._src import xla_bridge
+    if xla_bridge.backends_are_initialized():
+        raise RuntimeError("pin_to_cpu: a JAX backend is already "
+                           "initialised in this process")
+    jax.config.update("jax_platforms", "cpu")
+
+
 def device_main(cfg: dict):
     """Spawn entrypoint (top-level so multiprocessing can pickle it)."""
+    pin_to_cpu()
     DeviceWorker(cfg).run()

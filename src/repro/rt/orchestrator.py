@@ -139,6 +139,10 @@ class RTConfig:
         return -(-self.n_devices // self.cluster_size)
 
     def ccfg(self):
+        """unroll_clients: the in-process reference then runs each
+        client's device pass as its own plain convs, as every worker does
+        — vmap over client weights lowers to a grouped conv, which
+        XLA:CPU does not round like the per-client one."""
         from repro.configs.base import CPSLConfig
         return CPSLConfig(
             cut_layer=self.cut, n_clusters=self.n_clusters,
@@ -146,7 +150,7 @@ class RTConfig:
             lr_device=self.lr_device, lr_server=self.lr_server,
             batch_per_device=self.batch, optimizer=self.optimizer,
             momentum=self.momentum, weight_decay=self.weight_decay,
-            fused_step=False)
+            fused_step=False, unroll_clients=True)
 
     def data_spec(self) -> dict:
         return {"n_train": self.n_train, "n_test": self.n_test,
@@ -563,7 +567,20 @@ def run_elastic(cfg: RTConfig, max_restarts: int = 5):
     restart it with ``resume_from=`` so it adopts the WAL's last
     committed round — surviving workers REJOIN, missing ones are
     respawned. Returns (final state restored from the WAL, trace
-    records) — the same contract as ``run_loopback``."""
+    records) — the same contract as ``run_loopback``.
+
+    The orchestrator child is the server and needs the accelerator, which
+    belongs to one process at a time: the caller must not have touched a
+    JAX backend (other than the CPU's), and this parent stays off JAX
+    until the last child has exited."""
+    from jax._src import xla_bridge
+    if xla_bridge.backends_are_initialized():
+        import jax
+        if jax.default_backend() != "cpu":
+            raise RuntimeError(
+                "run_elastic: this process already holds the "
+                f"{jax.default_backend()} backend; its orchestrator child "
+                "could not open the chip")
     if not cfg.wal_dir or not cfg.trace_path:
         raise ValueError(
             "run_elastic needs cfg.wal_dir and cfg.trace_path — the WAL "
@@ -609,12 +626,13 @@ def run_elastic(cfg: RTConfig, max_restarts: int = 5):
 
 # -- the in-process reference --------------------------------------------
 
-def loopback_reference(cfg: RTConfig, zero_weight=None):
+def loopback_reference(cfg: RTConfig, zero_weight=None, zero_rounds=None):
     """The in-process looped reference for cfg's fixed contiguous plan:
     what a fault-free (or losslessly *recovered*) deployment must
     reproduce bit for bit. ``zero_weight=(m, k)`` zeroes one device's
-    eq.-8 weight in every round — the simulated-dropout semantics a
-    genuinely-lost upload must match. Returns (state, last-round loss).
+    eq.-8 weight in every round, or only in ``zero_rounds`` when given —
+    the simulated-dropout semantics a genuinely-lost upload must match.
+    Returns (state, last-round loss).
     """
     import jax
     from repro.core.cpsl import CPSL
@@ -629,15 +647,17 @@ def loopback_reference(cfg: RTConfig, zero_weight=None):
     clusters = [list(range(m * K, min((m + 1) * K, cfg.n_devices)))
                 for m in range(cfg.n_clusters)]
     sizes = [ds.data_sizes(c) for c in clusters]
+    dropped = [s.copy() for s in sizes]
     if zero_weight is not None:
         m, k = zero_weight
-        sizes[m] = sizes[m].copy()
-        sizes[m][k] = 0.0
+        dropped[m][k] = 0.0
     loss = None
     for rnd in range(cfg.rounds):
         def batch_fn(m, l, _rnd=rnd):
             return ds.cluster_batch(clusters[m],
                                     seed=batch_seed(cfg.seed, _rnd, m, l))
-        state, metrics = cpsl.run_round(state, batch_fn, data_sizes=sizes)
+        drop = zero_rounds is None or rnd in zero_rounds
+        state, metrics = cpsl.run_round(
+            state, batch_fn, data_sizes=dropped if drop else sizes)
         loss = metrics["loss"]
     return state, loss

@@ -62,7 +62,8 @@ class MsgType(enum.IntEnum):
     SHUTDOWN = 9       # server -> device: {}
     BYE = 10           # device -> server: {device}
     ERROR = 11         # server -> device: {reason} (e.g. dropped straggler)
-    READY = 12         # device -> server: warmup/jit done, {device}
+    READY = 12         # device -> server: warmup/jit done,
+                       # {device, platform}
     REJOIN = 13        # device -> server: already-built worker reconnecting
                        #                   after a server restart, {device}
     REJOIN_ACK = 14    # server -> device: {round, step} — the committed

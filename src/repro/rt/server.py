@@ -127,6 +127,8 @@ class RTServer:
         # ready = READY seen on the current connection; only the main
         # thread pumps the inbox, so READY handling is main-only
         self.ready: Set[int] = set()            # guarded-by: main-thread
+        # JAX platform each worker reported with READY (always "cpu")
+        self.platforms: Dict[int, str] = {}     # guarded-by: main-thread
         self._round_dropped: Set[int] = set()   # guarded-by: main-thread
         self._round_recovered: Set[int] = set()  # guarded-by: main-thread
         # GRAD/ACK replay caches: written and read exclusively by the
@@ -356,6 +358,7 @@ class RTServer:
             if mtype == MsgType.READY:
                 ready.add(gid)
                 self.ready.add(gid)
+                self.platforms[gid] = payload.get("platform")
                 with self._roster_lock:
                     self.last_seen[gid] = time.monotonic()
             else:

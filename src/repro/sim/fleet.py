@@ -67,7 +67,6 @@ from typing import Dict, List, Optional, Sequence
 import jax
 import jax.numpy as jnp
 import numpy as np
-from jax.experimental import enable_x64
 
 from repro import streams
 from repro.configs.base import SimFleetCfg
@@ -686,7 +685,7 @@ class SimFleetRunner:
 
         # per-episode innovation streams keyed by the episode SEED (same
         # seed -> same realization: CRN coupling across cuts/policies)
-        with enable_x64():
+        with jax.enable_x64(True):
             master = streams.fleet_master_key(dcfg.seed)
             draws = {}
             for sp in self.specs:
@@ -845,7 +844,8 @@ class SimFleetRunner:
         """The ``_sim`` argument dict (x64 device arrays).  Split out of
         ``run`` so static tooling (``repro.analysis.jit_audit``) can
         lower the exact program ``run`` dispatches without executing it.
-        Call under ``enable_x64()`` — the cost model's contract dtype."""
+        Call under ``jax.enable_x64(True)`` — the cost model's contract
+        dtype."""
         data = {"mu_f": jnp.asarray(self._mu_f),
                 "mu_snr": jnp.asarray(self._mu_snr),
                 "eta_f0": jnp.asarray(self._eta_f0),
@@ -872,7 +872,7 @@ class SimFleetRunner:
         """One jitted dispatch for the whole grid. Returns ``{"episodes":
         [spec + latency_s/sim_time_s/n_active curves], "trace": {episode-
         major arrays}, "wall_s"}``."""
-        with enable_x64():
+        with jax.enable_x64(True):
             data = self.sim_inputs()
             t0 = time.monotonic()
             ys = self._sim(data)

@@ -141,12 +141,11 @@ def test_jit002_host_callback():
 def test_jit003_f64_cast():
     import jax
     import jax.numpy as jnp
-    from jax.experimental import enable_x64
 
     def c(x):
         return x.astype(jnp.float64).sum()
 
-    with enable_x64():
+    with jax.enable_x64(True):
         traced = jax.jit(c).trace(jnp.zeros(3, jnp.float32))
         lowered = traced.lower()
     fs = jit_audit.audit_traced("c", traced, lowered, donated_leaves=0)

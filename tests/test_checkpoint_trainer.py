@@ -88,6 +88,31 @@ def test_failure_resume_bit_exact(tmp_path):
         assert jnp.array_equal(a, b)
 
 
+def test_trainer_releases_initial_state(tmp_path):
+    """Once round 0 has produced a new state, the trainer holds no
+    reference to the initial one: each extra copy is a whole model in
+    device memory."""
+    import gc
+    import weakref
+    tr = _mk_trainer(str(tmp_path), rounds=2)
+    init_state, run_round = tr.cpsl.init_state, tr.cpsl.run_round
+    refs, alive = [], []
+
+    def init_spy(key):
+        state = init_state(key)
+        refs.append(weakref.ref(jax.tree.leaves(state["srv"])[0]))
+        return state
+
+    def round_spy(*args, **kwargs):
+        gc.collect()
+        alive.append(refs[0]() is not None)
+        return run_round(*args, **kwargs)
+
+    tr.cpsl.init_state, tr.cpsl.run_round = init_spy, round_spy
+    tr.run(KEY)
+    assert alive == [True, False]
+
+
 def test_trainer_tracks_simulated_latency(tmp_path):
     tr = _mk_trainer(str(tmp_path), rounds=2)
     tr.run(KEY)

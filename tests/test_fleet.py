@@ -10,7 +10,8 @@ The contract has four layers, each pinned here:
      and the ``scan_rounds`` + im2col lowering;
   2. fleet — replica r of a homogeneous fleet is bit-exact (int/rng)
      and ULP-equal (float) to the solo curve with seed r, including
-     per-replica ``lr_scale`` applied as data;
+     per-replica ``lr_scale`` applied as data (on the im2col lowering;
+     direct convs under vmap hold a per-step ULP bound);
   3. padding — in a heterogeneous (padded + masked) fleet, padded slots
      never contribute: perturbing their index-table entries leaves every
      output bit-identical, padded metric slots come back NaN, and each
@@ -115,8 +116,11 @@ def test_training_curve_matches_looped_rounds(lowering):
 def test_fleet_replicas_match_solo_runs():
     """Replica r (its own seed, shard table, and lr_scale-as-data) vs
     the solo curve at seed r: int/rng leaves bit-exact, float leaves
-    ULP-equal; loss curves agree."""
-    cp = _cpsl(_ccfg())
+    ULP-equal; loss curves agree. On the im2col lowering that fleets use:
+    XLA:CPU's batched direct-conv backward is not bit-identical to the
+    solo one (see test_fleet_direct_conv_step_ulp_bound), and over a
+    curve a ReLU sign flip turns those ULPs into a real divergence."""
+    cp = _cpsl(_ccfg(conv_impl="im2col"))
     seeds = [0, 1, 2]
     shards = [non_iid_split(_YTR, n_devices=6, samples_per_device=60,
                             seed=s) for s in seeds]
@@ -155,6 +159,32 @@ def test_lr_scale_matches_baked_lr():
     for a, b in zip(jax.tree.leaves(s_scaled), jax.tree.leaves(s_baked),
                     strict=True):
         assert jnp.array_equal(a, b)
+
+
+def test_fleet_direct_conv_step_ulp_bound():
+    """One fused step with direct convs, vmapped over replicas vs solo:
+    XLA:CPU lowers the batched conv backward differently from the solo
+    one, so float leaves agree to 16 ULP of the leaf's magnitude, not
+    bitwise (INVARIANTS.md, "XLA:CPU ULP bounds")."""
+    cp = _cpsl(_ccfg())
+    dsd = _dsd()
+    states = cp.init_fleet_state([0, 1, 2])
+    idx = dsd.round_index_table(CLUSTERS, 0, 0, L)[0, 0]
+    batch = jax.tree.map(lambda a: a[idx], dsd.data)
+
+    def step(s):
+        return cp.fused_step_impl(s, batch)[0]
+
+    fleet = jax.jit(jax.vmap(step))(states)
+    for e in range(3):
+        solo = jax.jit(step)(jax.tree.map(lambda t: t[e], states))
+        for a, b in zip(jax.tree.leaves(solo), jax.tree.leaves(fleet)):
+            b = b[e]
+            if jnp.issubdtype(a.dtype, jnp.floating):
+                tol = 16 * ULP * float(jnp.abs(a).max())
+                assert float(jnp.abs(a - b).max()) <= tol
+            else:
+                assert jnp.array_equal(a, b)
 
 
 # --------------------------------------------------------------------------
@@ -310,18 +340,23 @@ def test_in_jit_eval_matches_host_eval():
 
 
 def test_im2col_conv_bit_identical():
-    """The im2col lowering's forward pass is bit-identical to the direct
-    conv on both paddings (the fleet's conv_impl swap changes lowering,
-    not semantics)."""
+    """The im2col lowering's forward pass equals the direct conv on both
+    paddings up to summation order (the fleet's conv_impl swap changes
+    lowering, not semantics). XLA:CPU's direct conv accumulates the 9C
+    products in another order than the dot, so elementwise the two agree
+    to 4 eps of sum |x||w| + |b| over the receptive field (measured
+    2.7), not bitwise (INVARIANTS.md, "XLA:CPU ULP bounds")."""
     key = jax.random.PRNGKey(3)
     x = jax.random.normal(key, (2, 12, 12, 8))
     w = jax.random.normal(jax.random.PRNGKey(4), (3, 3, 8, 16)) * 0.1
     b = jax.random.normal(jax.random.PRNGKey(5), (16,))
     from jax import lax
+    dn = ("NHWC", "HWIO", "NHWC")
     for pad in ("VALID", "SAME"):
-        direct = lax.conv_general_dilated(
-            x, w, (1, 1), pad,
-            dimension_numbers=("NHWC", "HWIO", "NHWC")) + b
-        assert jnp.array_equal(jax.jit(lenet.conv_im2col,
-                                       static_argnums=3)(x, w, b, pad),
-                               direct)
+        direct = lax.conv_general_dilated(x, w, (1, 1), pad,
+                                          dimension_numbers=dn) + b
+        scale = lax.conv_general_dilated(jnp.abs(x), jnp.abs(w), (1, 1),
+                                         pad, dimension_numbers=dn) \
+            + jnp.abs(b)
+        im2col = jax.jit(lenet.conv_im2col, static_argnums=3)(x, w, b, pad)
+        assert bool(jnp.all(jnp.abs(im2col - direct) <= 4 * ULP * scale))

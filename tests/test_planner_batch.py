@@ -163,6 +163,14 @@ def test_saa_multichain_means_never_worse():
 # PartitionBatch == summed scalar cluster_latency (0 ULP)
 # --------------------------------------------------------------------------
 
+def _left_to_right(values):
+    """Accumulate m = 0, 1, ... in order (eq. 25 as the planners sum)."""
+    total = 0.0
+    for x in values:
+        total += x
+    return total
+
+
 def _random_partition_case(seed, n=9, sizes=(4, 3, 2), L=1,
                            physical_gradients=False):
     rng = np.random.default_rng(seed)
@@ -176,8 +184,9 @@ def _random_partition_case(seed, n=9, sizes=(4, 3, 2), L=1,
 @pytest.mark.parametrize("seed,L,phys", [(0, 1, False), (3, 3, False),
                                          (8, 2, True), (17, 1, False)])
 def test_partition_batch_matches_scalar_sum(seed, L, phys):
-    """Totals match the left-to-right Python sum of per-cluster scalar
-    ``cluster_latency`` calls to 0 ULP, per-cluster values elementwise."""
+    """Totals match the left-to-right sum of per-cluster scalar
+    ``cluster_latency`` calls to 0 ULP, per-cluster values elementwise.
+    (Not Python's ``sum``: it compensates float rounding since 3.12.)"""
     net, ncfg, dev, xs, sizes, L, phys = _random_partition_case(
         seed, L=L, physical_gradients=phys)
     bounds = np.concatenate([[0], np.cumsum(sizes)])
@@ -190,7 +199,7 @@ def test_partition_batch_matches_scalar_sum(seed, L, phys):
                                   PROF, 16, L, physical_gradients=phys)
                for s, e in zip(bounds[:-1], bounds[1:])]
         np.testing.assert_array_equal(got_per[r], per)
-        assert got_tot[r] == sum(per)
+        assert got_tot[r] == _left_to_right(per)
         assert got_tot[r] == lt.round_latency(
             2, [dev[r, s:e] for s, e in zip(bounds[:-1], bounds[1:])],
             [xs[r, s:e] for s, e in zip(bounds[:-1], bounds[1:])],
@@ -205,9 +214,10 @@ def test_partition_batch_matches_scalar_sum_property(seed, v):
     pb = PartitionBatch(v, net, ncfg, PROF, 16, L, sizes, dev)
     got = pb.latencies(xs)
     for r in range(dev.shape[0]):
-        want = sum(lt.cluster_latency(v, dev[r, s:e], xs[r, s:e], net,
-                                      ncfg, PROF, 16, L)
-                   for s, e in zip(bounds[:-1], bounds[1:]))
+        want = _left_to_right(
+            lt.cluster_latency(v, dev[r, s:e], xs[r, s:e], net, ncfg,
+                               PROF, 16, L)
+            for s, e in zip(bounds[:-1], bounds[1:]))
         assert got[r] == want
 
 
@@ -231,9 +241,10 @@ def test_partition_batch_per_replica_cuts_and_nets():
                         net_rows=rows)
     got = pb.latencies(xs)
     for r in range(R):
-        want = sum(lt.cluster_latency(int(vs[r]), dev[r, s:e], xs[r, s:e],
-                                      nets[rows[r]], ncfg, PROF, 16, 2)
-                   for s, e in zip(bounds[:-1], bounds[1:]))
+        want = _left_to_right(
+            lt.cluster_latency(int(vs[r]), dev[r, s:e], xs[r, s:e],
+                               nets[rows[r]], ncfg, PROF, 16, 2)
+            for s, e in zip(bounds[:-1], bounds[1:]))
         assert got[r] == want
 
 

@@ -16,9 +16,10 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
+from repro.rt.device import pin_to_cpu
 from repro.rt.faults import FaultRule
-from repro.rt.orchestrator import (RTConfig, loopback_reference,
-                                   run_loopback)
+from repro.rt.orchestrator import (Orchestrator, RTConfig,
+                                   loopback_reference, run_loopback)
 from repro.rt.protocol import MsgType
 
 STATE_KEYS = ("dev", "srv", "dev_opt", "srv_opt", "step")
@@ -137,3 +138,23 @@ def test_wait_policy_rides_out_slow_device():
     rec = round_records(records)[0]
     assert rec["dropped"] == []
     assert rec["wall_s"] > 1.0
+
+
+def test_workers_pinned_to_cpu():
+    """Every spawned worker runs JAX on the CPU — it never opens the
+    accelerator the server process holds — and says so in READY."""
+    orch = Orchestrator(_cfg(trace_path=None))
+    try:
+        orch.start()
+        orch.run()
+        assert orch.server.platforms == {0: "cpu", 1: "cpu"}
+    finally:
+        orch.stop()
+
+
+def test_pin_to_cpu_refuses_after_backend_init():
+    """The pin must precede the first backend: once one is up, JAX has
+    fixed its platforms and the pin raises instead of silently failing."""
+    jax.devices()
+    with pytest.raises(RuntimeError, match="already initialised"):
+        pin_to_cpu()
