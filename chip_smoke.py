@@ -63,26 +63,6 @@ TOL_RT = 5e-2            # rt deployment vs the CPU reference, three rounds
 TOL_LM_FIRST_LOSS = 1.5  # |first round loss - ln(vocab)|
 
 
-class CompileCounters:
-    """Backend-compile seconds (persistent-cache reads included) and
-    persistent-cache hits, from JAX's monitoring events."""
-
-    def __init__(self):
-        import jax
-        self.compile_s, self.cache_hits = 0.0, 0
-        jax.monitoring.register_event_duration_secs_listener(
-            self._on_duration)
-        jax.monitoring.register_event_listener(self._on_event)
-
-    def _on_duration(self, event, duration, **_):
-        if event == "/jax/core/compile/backend_compile_duration":
-            self.compile_s += duration
-
-    def _on_event(self, event, **_):
-        if event == "/jax/compilation_cache/cache_hits":
-            self.cache_hits += 1
-
-
 def run_phase(counters, name, fn, *args):
     """Run one phase and print its line; exceptions propagate."""
     import jax
@@ -389,9 +369,9 @@ def main():
               f"{dev.platform!r}", file=sys.stderr)
         return 1
 
-    from repro import compile_cache
+    from repro import compile_cache, telemetry
     cache_dir = compile_cache.enable()
-    counters = CompileCounters()
+    counters = telemetry.compile_counter()
     shutil.rmtree(OUT, ignore_errors=True)
     OUT.mkdir(parents=True)
     print(json.dumps({"device_kind": dev.device_kind,

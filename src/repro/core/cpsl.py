@@ -40,6 +40,13 @@ Three orchestration levels, each one jit bigger than the last:
 
 Vanilla SL is CPSL with cluster_size=1 / n_clusters=N (paper §III). FL is
 the v=V degenerate case (`FLTrainer`).
+
+Device-side phases carry ``jax.named_scope`` names, so a profiler trace
+says which phase each device op belongs to: ``device_side`` (the device
+model, forward and backward), ``server_side`` (the server model and its
+loss), ``update`` (both optimizer steps) and ``fedavg`` (eq. 8). Backward
+ops inherit the scope (``transpose(jvp(server_side))/...``). Scopes change
+op metadata only, not the compiled code.
 """
 from __future__ import annotations
 
@@ -49,7 +56,7 @@ from typing import Callable, List, Optional
 import jax
 import jax.numpy as jnp
 
-from repro import streams
+from repro import streams, telemetry
 from repro import optim
 from repro.configs.base import CPSLConfig
 from repro.core import compression as cmp
@@ -111,9 +118,10 @@ class CPSL:
         Results match the vmapped lowering to ULP (tested); TPU/GPU are
         indifferent, so ``unroll_clients`` stays off by default."""
         K = jax.tree.leaves(dev)[0].shape[0]
-        outs = [self.split.device_apply(jax.tree.map(lambda t: t[k], dev),
-                                        jax.tree.map(lambda t: t[k], batch))
-                for k in range(K)]
+        with jax.named_scope("device_side"):
+            outs = [self.split.device_apply(
+                jax.tree.map(lambda t: t[k], dev),
+                jax.tree.map(lambda t: t[k], batch)) for k in range(K)]
         return (jnp.stack([o[0] for o in outs]),
                 jnp.stack([o[1] for o in outs]))
 
@@ -122,14 +130,15 @@ class CPSL:
         if self.ccfg.share_device_params:
             flat = _flat(batch)
             dev0 = jax.tree.map(lambda t: t[0], dev)
-            smashed, aux_d = self.split.device_apply(dev0, flat)
+            with jax.named_scope("device_side"):
+                smashed, aux_d = self.split.device_apply(dev0, flat)
         else:
             if self.ccfg.unroll_clients:
                 smashed, aux_d = self._clients_unrolled(dev, batch)
             else:
                 K = jax.tree.leaves(dev)[0].shape[0]
                 ax = pt.spmd_client_axes(K)
-                with pt.exclude_axes(ax):
+                with pt.exclude_axes(ax), jax.named_scope("device_side"):
                     smashed, aux_d = jax.vmap(
                         self.split.device_apply, spmd_axis_name=ax)(dev,
                                                                     batch)
@@ -138,7 +147,8 @@ class CPSL:
             aux_d = aux_d.mean()
             flat = _flat(batch)
         smashed = pt.shard(smashed, "batch")
-        loss, aux_s = self.split.server_loss(srv, smashed, flat)
+        with jax.named_scope("server_side"):
+            loss, aux_s = self.split.server_loss(srv, smashed, flat)
         total = loss + aux_d + aux_s
         return total, {"loss": loss, "aux": aux_d + aux_s}
 
@@ -182,12 +192,13 @@ class CPSL:
         else:
             (_, metrics), (g_dev, g_srv) = grad_fn(state["dev"],
                                                    state["srv"], batch)
-        new_dev, dev_opt = self.dev_opt.step(g_dev, state["dev_opt"],
-                                             state["dev"], state["step"],
-                                             lr_scale=lr_scale)
-        new_srv, srv_opt = self.srv_opt.step(g_srv, state["srv_opt"],
-                                             state["srv"], state["step"],
-                                             lr_scale=lr_scale)
+        with jax.named_scope("update"):
+            new_dev, dev_opt = self.dev_opt.step(g_dev, state["dev_opt"],
+                                                 state["dev"], state["step"],
+                                                 lr_scale=lr_scale)
+            new_srv, srv_opt = self.srv_opt.step(g_srv, state["srv_opt"],
+                                                 state["srv"], state["step"],
+                                                 lr_scale=lr_scale)
         state = dict(state, dev=new_dev, dev_opt=dev_opt, srv=new_srv,
                      srv_opt=srv_opt, step=state["step"] + 1)
         return state, metrics
@@ -211,7 +222,7 @@ class CPSL:
         if self.ccfg.unroll_clients:
             smashed, _ = self._clients_unrolled(state["dev"], batch)
         else:
-            with pt.exclude_axes(ax):
+            with pt.exclude_axes(ax), jax.named_scope("device_side"):
                 smashed, _ = jax.vmap(split.device_apply,
                                       spmd_axis_name=ax)(state["dev"], batch)
         K, B = smashed.shape[:2]
@@ -220,22 +231,25 @@ class CPSL:
 
         # Phase 2 (eqs. 5-6): server FP/BP; emits smashed-data gradient
         def srv_loss(srv, sm):
-            loss, aux = split.server_loss(srv, sm, flat)
+            with jax.named_scope("server_side"):
+                loss, aux = split.server_loss(srv, sm, flat)
             return loss + aux, loss
 
         (_, loss), (g_srv, g_smashed) = jax.value_and_grad(
             srv_loss, argnums=(0, 1), has_aux=True)(state["srv"],
                                                     smashed_flat)
-        new_srv, srv_opt = self.srv_opt.step(g_srv, state["srv_opt"],
-                                             state["srv"], state["step"],
-                                             lr_scale=lr_scale)
+        with jax.named_scope("update"):
+            new_srv, srv_opt = self.srv_opt.step(g_srv, state["srv_opt"],
+                                                 state["srv"], state["step"],
+                                                 lr_scale=lr_scale)
 
         # Phase 3 (eq. 7): device BP from the smashed gradient
         g_smashed = g_smashed.reshape(smashed.shape)
 
         def dev_bwd(dp, b, g):
-            _, vjp = jax.vjp(lambda q: split.device_apply(q, b)[0], dp)
-            return vjp(g)[0]
+            with jax.named_scope("device_side"):
+                _, vjp = jax.vjp(lambda q: split.device_apply(q, b)[0], dp)
+                return vjp(g)[0]
 
         if self.ccfg.unroll_clients:
             gs = [dev_bwd(jax.tree.map(lambda t: t[k], state["dev"]),
@@ -247,9 +261,10 @@ class CPSL:
                 g_dev = jax.vmap(dev_bwd, spmd_axis_name=ax)(state["dev"],
                                                              batch,
                                                              g_smashed)
-        new_dev, dev_opt = self.dev_opt.step(g_dev, state["dev_opt"],
-                                             state["dev"], state["step"],
-                                             lr_scale=lr_scale)
+        with jax.named_scope("update"):
+            new_dev, dev_opt = self.dev_opt.step(g_dev, state["dev_opt"],
+                                                 state["dev"], state["step"],
+                                                 lr_scale=lr_scale)
         state = dict(state, dev=new_dev, dev_opt=dev_opt, srv=new_srv,
                      srv_opt=srv_opt, step=state["step"] + 1)
         return state, {"loss": loss, "aux": jnp.zeros(())}
@@ -269,33 +284,35 @@ class CPSL:
         into the scan): straggler dropout drawn from the carried rng,
         optional upload compression with error feedback, then the
         data-size-weighted mean broadcast back to every client row."""
-        ccfg = self.ccfg
-        w = weights.astype(jnp.float32)
-        if ccfg.straggler_dropout > 0:
-            rng, sub = jax.random.split(state["rng"])
-            keep = jax.random.bernoulli(
-                sub, 1.0 - ccfg.straggler_dropout, w.shape)
-            # never drop everyone
-            keep = keep.at[0].set(True)
-            w = w * keep
-            state = dict(state, rng=rng)
+        with jax.named_scope("fedavg"):
+            ccfg = self.ccfg
+            w = weights.astype(jnp.float32)
+            if ccfg.straggler_dropout > 0:
+                rng, sub = jax.random.split(state["rng"])
+                keep = jax.random.bernoulli(
+                    sub, 1.0 - ccfg.straggler_dropout, w.shape)
+                # never drop everyone
+                keep = keep.at[0].set(True)
+                w = w * keep
+                state = dict(state, rng=rng)
 
-        dev = state["dev"]
-        if ccfg.compress_uploads != "none":
-            ref = jax.tree.map(lambda t: t[:1], dev)   # broadcast model
-            delta = jax.tree.map(lambda t, r: t - r, dev, ref)
-            delta, ef = cmp.apply_with_error_feedback(
-                delta, state["ef"], ccfg.compress_uploads, ccfg.compress_topk)
-            dev = jax.tree.map(lambda r, d: r + d, ref, delta)
-            state = dict(state, ef=ef)
+            dev = state["dev"]
+            if ccfg.compress_uploads != "none":
+                ref = jax.tree.map(lambda t: t[:1], dev)   # broadcast model
+                delta = jax.tree.map(lambda t, r: t - r, dev, ref)
+                delta, ef = cmp.apply_with_error_feedback(
+                    delta, state["ef"], ccfg.compress_uploads,
+                    ccfg.compress_topk)
+                dev = jax.tree.map(lambda r, d: r + d, ref, delta)
+                state = dict(state, ef=ef)
 
-        def avg(t):
-            ww = w / jnp.maximum(w.sum(), 1e-12)
-            m = jnp.tensordot(ww, t.astype(jnp.float32), axes=(0, 0))
-            return jnp.broadcast_to(m[None].astype(t.dtype), t.shape)
+            def avg(t):
+                ww = w / jnp.maximum(w.sum(), 1e-12)
+                m = jnp.tensordot(ww, t.astype(jnp.float32), axes=(0, 0))
+                return jnp.broadcast_to(m[None].astype(t.dtype), t.shape)
 
-        new_dev = jax.tree.map(avg, dev)
-        return dict(state, dev=new_dev)
+            new_dev = jax.tree.map(avg, dev)
+            return dict(state, dev=new_dev)
 
     @functools.partial(jax.jit, static_argnums=0)
     def _fedavg(self, state, weights):
@@ -324,11 +341,19 @@ class CPSL:
         metrics = []
         for m in range(M):
             for l in range(self.ccfg.local_epochs):
-                state, mt = self.cluster_step(state, batch_fn(m, l))
+                batch = batch_fn(m, l)
+                with telemetry.span("step"):
+                    state, mt = self.cluster_step(state, batch)
+                telemetry.count("dispatches")
                 metrics.append(mt)
-            state = self.fedavg(
-                state, None if data_sizes is None else data_sizes[m])
-        loss = float(jnp.mean(jnp.stack([m["loss"] for m in metrics])))
+            with telemetry.span("fedavg"):
+                state = self.fedavg(
+                    state, None if data_sizes is None else data_sizes[m])
+            telemetry.count("dispatches")
+        loss = jnp.mean(jnp.stack([m["loss"] for m in metrics]))
+        with telemetry.span("sync"):
+            loss = float(loss)
+        telemetry.count("syncs")
         return state, {"loss": loss}
 
     # -- fused round (single donated jit over the (M, L) grid) ---------------

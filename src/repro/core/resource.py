@@ -7,11 +7,12 @@
 from __future__ import annotations
 
 import math
+import time
 from typing import List, Optional, Sequence, Tuple
 
 import numpy as np
 
-from repro import streams
+from repro import streams, telemetry
 from repro.core.channel import NetworkCfg, NetworkState, device_means, sample_network
 from repro.core.latency import CutProfile, PartitionBatch, cluster_latency
 
@@ -129,13 +130,19 @@ def brute_force_spectrum(v, devices, net, ncfg, prof, B, L,
 
 def _round_latency_cached(v, clusters, net, ncfg, prof, B, L, cache,
                           spectrum_fn=None):
+    """Round latency and spectrum of ``clusters``; a cluster not in
+    ``cache`` costs one Alg. 3 call (counted as ``spectrum_calls``, its
+    seconds as ``spectrum_s``)."""
     spectrum_fn = spectrum_fn or greedy_spectrum
     total = 0.0
     xs = []
     for ds in clusters:
         key = tuple(sorted(ds))
         if key not in cache:
+            t0 = time.perf_counter()
             cache[key] = spectrum_fn(v, list(key), net, ncfg, prof, B, L)
+            telemetry.count("spectrum_s", time.perf_counter() - t0)
+            telemetry.count("spectrum_calls")
         x, lat = cache[key]
         # the cached allocation is aligned with the sorted key; reorder it
         # to the cluster's own device order so (clusters, xs) stay paired
@@ -194,6 +201,7 @@ def gibbs_clustering(v: int, net: NetworkState, ncfg: NetworkCfg,
     hist = [cur]
     if n_clusters < 2:
         iters = 0          # nothing to swap
+    accepts = 0
     for it in range(iters):
         if draws is not None:
             # fixed uniform->index mapping, shared with the in-jit mirror
@@ -218,10 +226,13 @@ def gibbs_clustering(v: int, net: NetworkState, ncfg: NetworkCfg,
         accept_u = rng.random() if draws is None else float(prop_u[it][4])
         if accept_u < eps:
             clusters, cur, xs = cand, new, new_xs
+            accepts += 1
         if cur < best[0]:
             best = (cur, [list(c) for c in clusters], [x.copy() for x in xs])
         if track:
             hist.append(cur)
+    telemetry.count("gibbs_iters", iters)
+    telemetry.count("gibbs_accepts", accepts)
     lat, cl, xs = best
     if track:
         return cl, xs, lat, hist

@@ -15,15 +15,23 @@ the typed view — ``from_dict`` parses any producer's record (unknown
 keys land in ``extras``), and ``to_dict`` emits exactly the non-None
 fields, so parse -> emit is the identity on schema-conforming records
 (tests/test_telemetry.py pins the roundtrip).
+
+The process's own spans and counters live here too (``span``, ``count``,
+``compile_counter``): the trainer opens spans at its layer boundaries and
+folds each round's into the round record as ``phase_s`` and ``counts``.
 """
 from __future__ import annotations
 
+import contextlib
 import dataclasses
 import json
 import os
+import threading
+import time
 import warnings
+from collections import defaultdict
 from dataclasses import dataclass, field
-from typing import Any, Dict, List, Optional, Union
+from typing import Any, Dict, List, Optional, Tuple, Union
 
 import numpy as np
 
@@ -103,6 +111,8 @@ class RoundRecord(_Record):
                                            # back via lossless retry
     source: Optional[str] = None          # "sim" | "rt"
     events: Optional[List[dict]] = None
+    phase_s: Optional[Dict[str, float]] = None  # seconds per span name
+    counts: Optional[Dict[str, float]] = None   # the round's counters
     extras: Dict[str, Any] = field(default_factory=dict)
 
 
@@ -200,3 +210,129 @@ def load_trace(path: str, tolerate_torn_tail: bool = True) -> List[dict]:
                 f"{path}: corrupt trace line {i + 1} of {len(lines)}: {e}"
             ) from e
     return out
+
+
+# --------------------------------------------------------------------------
+# In-process spans and counters
+# --------------------------------------------------------------------------
+
+@dataclass
+class Span:
+    """One closed span. ``start_ns``/``end_ns`` are ``time.time_ns()``,
+    the epoch clock the JAX profiler stamps host events with: subtract a
+    trace's ``profile_start_time`` and they line up with its events."""
+    name: str
+    parent: Optional[str]
+    round: Optional[int]
+    start_ns: int
+    end_ns: int
+
+
+class CompileCounter:
+    """Backend compiles, their seconds, and persistent-cache hits, from
+    JAX's monitoring events. JAX keeps its listeners for the life of the
+    process, so ``compile_counter()`` registers one instance, once."""
+
+    def __init__(self):
+        import jax
+        self.compiles, self.compile_s, self.cache_hits = 0, 0.0, 0
+        jax.monitoring.register_event_duration_secs_listener(self._duration)
+        jax.monitoring.register_event_listener(self._event)
+
+    def _duration(self, event, duration, **_):
+        if event == "/jax/core/compile/backend_compile_duration":
+            self.compiles += 1
+            self.compile_s += duration
+
+    def _event(self, event, **_):
+        if event == "/jax/compilation_cache/cache_hits":
+            self.cache_hits += 1
+
+
+_COMPILES: Optional[CompileCounter] = None
+_COMPILES_LOCK = threading.Lock()
+
+
+def compile_counter() -> CompileCounter:
+    """The process's compile counter, registered on first use."""
+    global _COMPILES
+    with _COMPILES_LOCK:
+        if _COMPILES is None:
+            _COMPILES = CompileCounter()
+        return _COMPILES
+
+
+class Recorder:
+    """The current round's spans and counters, in memory.
+
+    One recorder serves the process (``span``, ``count`` and
+    ``begin_round`` below use it), because the planner's counters are hit
+    from ``core.resource`` functions that no trainer is passed to; hit
+    outside a trainer round (``SimEngine``, sweeps, tests) they cost the
+    same and fold into no record. Spans nest on one stack: open them from
+    the thread that runs the rounds. Nothing here reads a device value,
+    so recording adds no host-device sync."""
+
+    def __init__(self):
+        self.round: Optional[int] = None
+        self.spans: List[Span] = []
+        self.counts: Dict[str, float] = defaultdict(int)
+        self._open: List[str] = []
+        self._lock = threading.Lock()
+        self._compiles0 = 0
+        self._annotation = None    # jax.profiler.TraceAnnotation, on use
+
+    def begin_round(self, rnd: int):
+        """Forget the last round's spans and counters; spans opened from
+        here on carry ``rnd``."""
+        self.round = rnd
+        self.spans = []
+        with self._lock:
+            self.counts = defaultdict(int)
+        self._compiles0 = compile_counter().compiles
+
+    @contextlib.contextmanager
+    def span(self, name: str):
+        """Time the block as span ``name`` (its parent is the innermost
+        open span), inside ``jax.profiler.TraceAnnotation("cpsl." +
+        name)``, which lands in a profiler trace when one is running and
+        costs about half a microsecond when none is."""
+        if self._annotation is None:
+            from jax.profiler import TraceAnnotation
+            self._annotation = TraceAnnotation
+        parent = self._open[-1] if self._open else None
+        self._open.append(name)
+        start = time.time_ns()
+        try:
+            with self._annotation("cpsl." + name):
+                yield
+        finally:
+            self.spans.append(Span(name, parent, self.round, start,
+                                   time.time_ns()))
+            self._open.pop()
+
+    def count(self, name: str, n: float = 1):
+        """Add ``n`` to counter ``name``; a float ``n`` makes it a
+        seconds accumulator, for work too fine-grained to span."""
+        with self._lock:
+            self.counts[name] += n
+
+    def fold(self) -> Tuple[Dict[str, float], Dict[str, float]]:
+        """(``phase_s``, ``counts``) of the round so far: seconds per span
+        name, summed, and the counters, with ``compiles`` the backend
+        compiles since ``begin_round``."""
+        phase_ns: Dict[str, int] = defaultdict(int)
+        for s in self.spans:
+            phase_ns[s.name] += s.end_ns - s.start_ns
+        phase = {name: ns / 1e9 for name, ns in phase_ns.items()}
+        with self._lock:
+            counts = dict(self.counts)
+        counts["compiles"] = compile_counter().compiles - self._compiles0
+        return phase, counts
+
+
+RECORDER = Recorder()
+span = RECORDER.span
+count = RECORDER.count
+begin_round = RECORDER.begin_round
+fold = RECORDER.fold

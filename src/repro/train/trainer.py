@@ -34,7 +34,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from repro import streams
+from repro import streams, telemetry
 from repro.configs.base import CPSLConfig, FleetConfig
 from repro.core import latency as lt
 from repro.core import resource as rs
@@ -114,8 +114,22 @@ class CPSLTrainer:
     # -- round-level resource management (paper small timescale) -------------
 
     def _plan_round(self, v: int, rnd: int):
-        rng = streams.trainer_round_rng(self.tcfg.seed, rnd)
-        net = sample_network(self.ncfg, self.mu_f, self.mu_snr, rng)
+        with telemetry.span("plan"):
+            rng = streams.trainer_round_rng(self.tcfg.seed, rnd)
+            with telemetry.span("network"):
+                net = sample_network(self.ncfg, self.mu_f, self.mu_snr, rng)
+            with telemetry.span("cluster"):
+                clusters, xs, lat = self._cluster(v, rnd, net)
+            if self._prof_compressed is not None:
+                lat = lt.round_latency(v, clusters, xs, net, self.ncfg,
+                                       self._prof_compressed,
+                                       self.cpsl.ccfg.batch_per_device,
+                                       self.cpsl.ccfg.local_epochs)
+        return clusters, xs, lat
+
+    def _cluster(self, v: int, rnd: int, net):
+        """Clusters and spectrum by ``resource_mgmt``: (clusters, xs,
+        priced latency)."""
         M, K = self.cpsl.ccfg.n_clusters, self.cpsl.ccfg.cluster_size
         kind = self.tcfg.resource_mgmt
         if kind == "gibbs":
@@ -142,11 +156,6 @@ class CPSLTrainer:
                 self.cpsl.ccfg.batch_per_device,
                 self.cpsl.ccfg.local_epochs, M, K,
                 seed=(0 if kind == "fixed" else self.tcfg.seed + rnd))
-        if self._prof_compressed is not None:
-            lat = lt.round_latency(v, clusters, xs, net, self.ncfg,
-                                   self._prof_compressed,
-                                   self.cpsl.ccfg.batch_per_device,
-                                   self.cpsl.ccfg.local_epochs)
         return clusters, xs, lat
 
     # -- main loop ------------------------------------------------------------
@@ -174,36 +183,45 @@ class CPSLTrainer:
                 if self.tcfg.fail_at_round is not None \
                         and rnd == self.tcfg.fail_at_round:
                     raise SimulatedFailure(f"injected failure at round {rnd}")
+                telemetry.begin_round(rnd)
                 t0 = time.monotonic()
-                clusters, xs, lat = self._plan_round(v, rnd)
+                with telemetry.span("round"):
+                    clusters, xs, lat = self._plan_round(v, rnd)
+                    if self._ds_dev is not None:
+                        # fused round: one donated jit, batches gathered
+                        # on device from the precomputed index table; the
+                        # loss stays a device scalar until the next log
+                        # flush
+                        idx = self._ds_dev.round_index_table(
+                            clusters, self.tcfg.seed, rnd,
+                            self.cpsl.ccfg.local_epochs)
+                        state, metrics = self.cpsl.run_round_fused(
+                            state, self._ds_dev.data, idx,
+                            self._ds_dev.cluster_weights(clusters))
+                        # dispatch is async — wait for the device compute
+                        # so wall_s stays a real measurement (no host
+                        # transfer; the metric sync still batches per
+                        # log_every)
+                        with telemetry.span("sync"):
+                            jax.block_until_ready(state)
+                        telemetry.count("syncs")
+                    else:
+                        def batch_fn(m, l, _clusters=clusters, _rnd=rnd):
+                            with telemetry.span("gather"):
+                                b = self.ds.cluster_batch(
+                                    _clusters[m],
+                                    seed=batch_seed(self.tcfg.seed, _rnd,
+                                                    m, l))
+                                telemetry.count("h2d_bytes", sum(
+                                    t.nbytes for t in jax.tree.leaves(b)))
+                                return jax.tree.map(jnp.asarray, b)
 
-                if self._ds_dev is not None:
-                    # fused round: one donated jit, batches gathered on
-                    # device from the precomputed index table; the loss
-                    # stays a device scalar until the next log flush
-                    idx = self._ds_dev.round_index_table(
-                        clusters, self.tcfg.seed, rnd,
-                        self.cpsl.ccfg.local_epochs)
-                    state, metrics = self.cpsl.run_round_fused(
-                        state, self._ds_dev.data, idx,
-                        self._ds_dev.cluster_weights(clusters))
-                    # dispatch is async — wait for the device compute so
-                    # wall_s stays a real measurement (no host transfer;
-                    # the metric sync still batches per log_every)
-                    jax.block_until_ready(state)
-                else:
-                    def batch_fn(m, l, _clusters=clusters, _rnd=rnd):
-                        b = self.ds.cluster_batch(
-                            _clusters[m],
-                            seed=batch_seed(self.tcfg.seed, _rnd, m, l))
-                        return jax.tree.map(jnp.asarray, b)
-
-                    sizes = (np.stack([self.ds.data_sizes(c)
-                                       for c in clusters])
-                             if hasattr(self.ds, "data_sizes") else None)
-                    state, metrics = self.cpsl.run_round(
-                        state, batch_fn, n_clusters=len(clusters),
-                        data_sizes=sizes)
+                        sizes = (np.stack([self.ds.data_sizes(c)
+                                           for c in clusters])
+                                 if hasattr(self.ds, "data_sizes") else None)
+                        state, metrics = self.cpsl.run_round(
+                            state, batch_fn, n_clusters=len(clusters),
+                            data_sizes=sizes)
                 sim_time += lat
                 wall = time.monotonic() - t0
                 rec = {"round": rnd, "loss": metrics["loss"],
@@ -211,19 +229,22 @@ class CPSLTrainer:
                        "wall_s": wall}
                 if self.eval_fn is not None:
                     rec["eval"] = self.eval_fn(self.cpsl, state)
-                self.history.append(rec)
-                self._pending.append(rec)
 
                 last = rnd == self.tcfg.rounds - 1
+                if (rnd + 1) % self.tcfg.ckpt_every == 0 or last \
+                        or self._stop:
+                    with telemetry.span("save"):
+                        self.ckpt.save(
+                            {"round": jnp.asarray(rnd + 1, jnp.int32),
+                             "sim_time": jnp.asarray(sim_time),
+                             "state": state},
+                            step=rnd + 1, block=last or self._stop)
+                rec["phase_s"], rec["counts"] = telemetry.fold()
+                self.history.append(rec)
+                self._pending.append(rec)
                 if (rnd + 1) % self.tcfg.log_every == 0 or last \
                         or self._stop:
                     self._flush_logs()
-                if (rnd + 1) % self.tcfg.ckpt_every == 0 or last \
-                        or self._stop:
-                    self.ckpt.save({"round": jnp.asarray(rnd + 1, jnp.int32),
-                                    "sim_time": jnp.asarray(sim_time),
-                                    "state": state},
-                                   step=rnd + 1, block=last or self._stop)
                 if self._stop:
                     break
         finally:

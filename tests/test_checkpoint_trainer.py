@@ -88,6 +88,35 @@ def test_failure_resume_bit_exact(tmp_path):
         assert jnp.array_equal(a, b)
 
 
+def test_resumed_history_records_match(tmp_path):
+    """The round records of a resumed run equal the uninterrupted run's
+    rounds, their counters included; only times (and compiles, which the
+    process already holds or not) may differ. ``sim_time_s`` resumes from
+    the checkpoint's float32 scalar, so it agrees to float32 rounding."""
+    d1, d2 = str(tmp_path / "a"), str(tmp_path / "b")
+    tr_ref = _mk_trainer(d1, rounds=5)
+    tr_ref.run(KEY)
+    with pytest.raises(SimulatedFailure):
+        _mk_trainer(d2, rounds=5, fail_at=3).run(KEY)
+    tr2 = _mk_trainer(d2, rounds=5)
+    tr2.run(KEY)
+    timing = {"wall_s", "phase_s", "sim_time_s"}
+    untimed = {"compiles", "spectrum_s"}
+
+    def exact(h):
+        out = {k: v for k, v in h.items() if k not in timing}
+        out["counts"] = {k: v for k, v in h["counts"].items()
+                         if k not in untimed}
+        return out
+
+    assert [exact(h) for h in tr2.history] == \
+        [exact(h) for h in tr_ref.history[2:]]
+    for h, h_ref in zip(tr2.history, tr_ref.history[2:]):
+        assert h["sim_time_s"] == pytest.approx(h_ref["sim_time_s"],
+                                                rel=1e-7)
+        assert h["counts"]["dispatches"] == 2 + 2    # M * L + M
+
+
 def test_trainer_releases_initial_state(tmp_path):
     """Once round 0 has produced a new state, the trainer holds no
     reference to the initial one: each extra copy is a whole model in

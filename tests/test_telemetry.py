@@ -141,3 +141,186 @@ def test_load_trace_midfile_corruption_raises(tmp_path):
         f.write('{"round": 1}\n')
     with pytest.raises(ValueError, match="line 2 of 3"):
         load_trace(path)
+
+
+# -- in-process spans and counters -------------------------------------------
+
+def test_spans_nest_with_parents_and_round():
+    from repro import telemetry
+    rec = telemetry.Recorder()
+    rec.begin_round(7)
+    with rec.span("round"):
+        with rec.span("plan"):
+            with rec.span("cluster"):
+                pass
+        with rec.span("step"):
+            pass
+    got = [(s.name, s.parent, s.round) for s in rec.spans]
+    assert got == [("cluster", "plan", 7), ("plan", "round", 7),
+                   ("step", "round", 7), ("round", None, 7)]
+    by = {s.name: s for s in rec.spans}
+    assert by["round"].start_ns <= by["plan"].start_ns \
+        <= by["cluster"].start_ns <= by["cluster"].end_ns \
+        <= by["plan"].end_ns <= by["step"].start_ns <= by["round"].end_ns
+
+
+def test_phase_s_sums_spans_by_name():
+    import time
+    from repro import telemetry
+    rec = telemetry.Recorder()
+    rec.begin_round(0)
+    for _ in range(3):
+        with rec.span("step"):
+            time.sleep(0.002)
+    with rec.span("sync"):
+        pass
+    phase, _ = rec.fold()
+    steps = [s.end_ns - s.start_ns for s in rec.spans if s.name == "step"]
+    assert phase["step"] == sum(steps) / 1e9
+    assert phase["step"] >= 0.006 and set(phase) == {"step", "sync"}
+
+
+def test_counters_reset_each_round():
+    from repro import telemetry
+    rec = telemetry.Recorder()
+    rec.begin_round(0)
+    rec.count("dispatches")
+    rec.count("dispatches", 2)
+    rec.count("spectrum_s", 0.25)
+    _, counts = rec.fold()
+    assert counts["dispatches"] == 3 and counts["spectrum_s"] == 0.25
+    rec.begin_round(1)
+    rec.count("syncs")
+    _, counts = rec.fold()
+    assert counts == {"syncs": 1, "compiles": 0}
+    assert rec.spans == [] and rec.round == 1
+
+
+def test_compile_counter_counts_backend_compiles():
+    import time
+    import jax
+    from repro import telemetry
+    counter = telemetry.compile_counter()
+    assert telemetry.compile_counter() is counter     # one per process
+    # a constant no other program holds: a new program, compiled here
+    f = jax.jit(lambda x: x * 3.0 + float(time.time_ns() % 997))
+    x = np.ones(3, np.float32)
+    n0 = counter.compiles
+    f(x)
+    assert counter.compiles == n0 + 1
+    f(x)
+    assert counter.compiles == n0 + 1
+
+
+def test_span_shares_the_profiler_clock(tmp_path):
+    """A span's in-memory start, less the trace's ``profile_start_time``,
+    is where the trace puts its ``cpsl.<name>`` event."""
+    import jax
+    from jax.profiler import ProfileData
+    from repro import telemetry
+    rec = telemetry.Recorder()
+    rec.begin_round(0)
+    jax.profiler.start_trace(str(tmp_path))
+    try:
+        with rec.span("probe"):
+            sum(range(10000))
+    finally:
+        jax.profiler.stop_trace()
+    path = sorted(tmp_path.rglob("*.xplane.pb"))[-1]
+    data = ProfileData.from_file(str(path))
+    env = next(p for p in data.planes if p.name == "Task Environment")
+    start = dict(env.stats)
+    events = [e for p in data.planes for line in p.lines for e in line.events
+              if e.name == "cpsl.probe"]
+    assert len(events) == 1
+    span = rec.spans[0]
+    offset_ns = span.start_ns - start["profile_start_time"]
+    assert abs(events[0].start_ns - offset_ns) < 1e6
+
+
+def _trainer(tmp_path, fused=False, **kw):
+    from repro.configs.base import CPSLConfig
+    from repro.core.cpsl import CPSL
+    from repro.core.splitting import make_split_model
+    from repro.data.pipeline import CPSLDataset
+    from repro.data.synthetic import non_iid_split, synthetic_mnist
+    from repro.train.trainer import CPSLTrainer, TrainerCfg
+    xtr, ytr, _, _ = synthetic_mnist(1500, 100, seed=0)
+    idx = non_iid_split(ytr, n_devices=6, samples_per_device=80, seed=0)
+    ccfg = CPSLConfig(cut_layer=1, n_clusters=2, cluster_size=3,
+                      local_epochs=2, fused_round=fused, unroll_clients=fused)
+    tcfg = TrainerCfg(ckpt_dir=str(tmp_path), rounds=2, ckpt_every=2,
+                      resource_mgmt="gibbs", gibbs_iters=30, seed=5,
+                      async_ckpt=False, **kw)
+    return CPSLTrainer(CPSL(make_split_model("lenet", 1), ccfg),
+                       CPSLDataset(xtr, ytr, idx, batch=8), lenet_profile(),
+                       NetworkCfg(n_devices=6), tcfg)
+
+
+def test_trainer_round_records_phases_and_counts(tmp_path):
+    """A looped round: one dispatch per cluster step and FedAvg, one host
+    sync, the planner's Alg. 3 calls as Gibbs' cache misses, and the
+    round's spans inside its wall time."""
+    import jax
+    from repro import streams
+    from repro.core import resource as rs
+    from repro.core.channel import sample_network
+    tr = _trainer(tmp_path)
+    tr.run(jax.random.PRNGKey(0))
+    M, K, L = 2, 3, 2
+    for h in tr.history:
+        rnd, counts, phase = h["round"], h["counts"], h["phase_s"]
+        assert counts["dispatches"] == M * L + M
+        assert counts["syncs"] == 1
+        assert counts["gibbs_iters"] == 30
+        assert counts["h2d_bytes"] == M * L * K * 8 * (28 * 28 * 4 + 4)
+        misses = []
+
+        def spectrum(*a):
+            misses.append(a[1])
+            return rs.greedy_spectrum(*a)
+
+        net = sample_network(tr.ncfg, tr.mu_f, tr.mu_snr,
+                             streams.trainer_round_rng(5, rnd))
+        rs.gibbs_clustering(1, net, tr.ncfg, tr.prof,
+                            tr.cpsl.ccfg.batch_per_device, L, M, K,
+                            iters=30, seed=5 + rnd, spectrum_fn=spectrum)
+        assert counts["spectrum_calls"] == len(misses) > 0
+        assert counts["spectrum_s"] > 0
+        assert set(phase) >= {"round", "plan", "network", "cluster",
+                              "gather", "step", "fedavg", "sync"}
+        inside = sum(phase[n] for n in ("plan", "gather", "step", "fedavg",
+                                        "sync"))
+        assert inside <= phase["round"] <= h["wall_s"]
+        assert phase["network"] + phase["cluster"] <= phase["plan"]
+    assert "save" in tr.history[-1]["phase_s"]        # ckpt_every=2
+    assert tr.history[0]["counts"]["compiles"] > 0    # the first round
+    assert tr.history[1]["counts"]["compiles"] == 0   # compiles, no more
+
+
+def test_trainer_log_carries_phases_and_counts(tmp_path):
+    """The operator's ``--log`` JSONL holds each round's ``phase_s`` and
+    ``counts``, and they parse as ``RoundRecord`` fields."""
+    import jax
+    log = tmp_path / "log.jsonl"
+    tr = _trainer(tmp_path / "ckpt", log_path=str(log))
+    tr.run(jax.random.PRNGKey(0))
+    lines = load_trace(str(log))
+    assert [d["round"] for d in lines] == [0, 1]
+    for d in lines:
+        rec = parse_record(d)
+        assert rec.extras.keys() == {"sim_latency_s"}
+        assert rec.phase_s["round"] > 0 and rec.counts["syncs"] == 1
+
+
+def test_fused_round_records_one_sync(tmp_path):
+    """The fused round is one dispatch; its ``sync`` span is the wait for
+    the device, inside ``round``."""
+    import jax
+    tr = _trainer(tmp_path, fused=True)
+    tr.run(jax.random.PRNGKey(0))
+    for h in tr.history:
+        assert h["counts"]["syncs"] == 1
+        assert "dispatches" not in h["counts"]
+        assert h["phase_s"]["sync"] + h["phase_s"]["plan"] \
+            <= h["phase_s"]["round"] <= h["wall_s"]
