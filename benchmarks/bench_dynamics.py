@@ -82,7 +82,8 @@ def bench_batched_evaluation(quick: bool):
     # Gibbs: identical clusters/allocations/latency
     iters = 100 if quick else 400
     t_gi, a = _timeit(lambda: rs.gibbs_clustering(
-        v, net, ncfg, prof, B, L, 6, 5, iters=iters, seed=0), 2)
+        v, net, ncfg, prof, B, L, 6, 5, iters=iters, seed=0,
+        spectrum_fn=rs.greedy_spectrum), 2)
     t_gib, b = _timeit(lambda: gibbs_clustering_batched(
         v, net, ncfg, prof, B, L, 6, 5, iters=iters, seed=0), 2)
     assert a[0] == b[0] and a[2] == b[2] \

@@ -163,8 +163,10 @@ class PartitionBatch:
         self.num_g = xi_g                                # (20)
         self.num_t = c["xi_d"]                           # (23)
 
-    def cluster_latencies(self, xs: np.ndarray) -> np.ndarray:
-        """(R, N) allocations -> (R, M) per-cluster latencies D_m."""
+    def phase_terms(self, xs: np.ndarray):
+        """(R, N) allocations -> three (R, N) arrays: each device's summand
+        inside the phase maxima of eqs. (19), (22) and (24), before the
+        server's tau_e is added."""
         xs = np.asarray(xs, dtype=np.float64)
         if xs.ndim == 1:
             xs = xs[None, :]
@@ -173,11 +175,15 @@ class PartitionBatch:
         tau_g = self.num_g / xr                          # (20)
         tau_t = self.num_t / xr                          # (23)
         gu = tau_g + self.tau_u
+        return self.bd + tau_s, gu + self.tau_d + tau_s, gu + tau_t
+
+    def cluster_latencies(self, xs: np.ndarray) -> np.ndarray:
+        """(R, N) allocations -> (R, M) per-cluster latencies D_m."""
+        s, i, e = self.phase_terms(xs)
         mx = np.maximum.reduceat
-        d_S = mx(self.bd + tau_s, self.starts, axis=1) + self.tau_e  # (19)
-        d_I = mx(gu + self.tau_d + tau_s, self.starts, axis=1) \
-            + self.tau_e                                             # (22)
-        d_E = mx(gu + tau_t, self.starts, axis=1)                    # (24)
+        d_S = mx(s, self.starts, axis=1) + self.tau_e    # (19)
+        d_I = mx(i, self.starts, axis=1) + self.tau_e    # (22)
+        d_E = mx(e, self.starts, axis=1)                 # (24)
         return d_S + (self.L - 1) * d_I + d_E
 
     def latencies(self, xs: np.ndarray) -> np.ndarray:
@@ -198,16 +204,8 @@ class PartitionBatch:
         step's argmin to the k largest-score devices; only a straggler's
         increment can lower a phase max, so high-score devices are the
         only plausible winners."""
-        xs = np.asarray(xs, dtype=np.float64)
-        if xs.ndim == 1:
-            xs = xs[None, :]
-        xr = xs * self.r
-        tau_s = self.num_s / xr                          # (17)
-        tau_g = self.num_g / xr                          # (20)
-        tau_t = self.num_t / xr                          # (23)
-        gu = tau_g + self.tau_u
-        return (self.bd + tau_s) + (self.L - 1) * (gu + self.tau_d + tau_s) \
-            + (gu + tau_t)
+        s, i, e = self.phase_terms(xs)
+        return s + (self.L - 1) * i + e
 
 
 def cluster_latency_batch(v: int, devices: Sequence[int], xs: np.ndarray,

@@ -28,7 +28,11 @@ def greedy_spectrum(v: int, devices: Sequence[int], net: NetworkState,
     then repeatedly give one to the device yielding the lowest resulting
     cluster latency — i.e. argmin_k Omega_k, which (the current latency
     Omega being fixed across candidates) equals the paper's
-    argmax_k (Omega - Omega_k) largest-gain rule. Returns (x, D_m)."""
+    argmax_k (Omega - Omega_k) largest-gain rule. Returns (x, D_m).
+
+    The scalar reference: one ``cluster_latency`` call per candidate.
+    The planners run :class:`SpectrumTable`, which tests hold to it bit
+    for bit."""
     C = ncfg.n_subcarriers if C is None else C
     K = len(devices)
     assert C >= K, "need at least one subcarrier per device"
@@ -54,6 +58,98 @@ def greedy_spectrum(v: int, devices: Sequence[int], net: NetworkState,
         x[best_k] += 1
         cur = cands[best_k]
     return x, cur
+
+
+def _top2(a: List[float]) -> Tuple[float, int, float]:
+    """(largest of ``a``, its first index, largest of the others; -inf
+    where there are none)."""
+    m = max(a)
+    k = a.index(m)
+    a[k] = -math.inf
+    m2 = max(a)
+    a[k] = m
+    return m, k, m2
+
+
+class SpectrumTable:
+    """Alg. 3 from a table of per-device phase terms, built once for one
+    cut ``v``, one network draw and a budget of ``C`` subcarriers.
+
+    For each of ``devices`` and each x in 1..C the table holds the three
+    summands inside the phase maxima of ``cluster_latency``, eqs. (19),
+    (22) and (24) without tau_e (``PartitionBatch.phase_terms``: the same
+    float64 operations in the same order). A greedy step prices candidate
+    k from the largest and second largest current term of each phase:
+    with device k at x_k + 1 a phase's maximum is max(largest term of the
+    other devices, k's new term). Every term falls as x grows (each is a
+    sum of positive constants and of positive constants over x * r, and
+    rounding keeps that order), so a device that holds no phase maximum
+    lowers none: its candidate latency is the current one. Only the at
+    most three devices that hold a maximum are priced, the rest tie at
+    the current latency, and the first index wins a tie, as ``argmin``'s.
+    Max is exact and every sum is the one ``cluster_latency`` makes in
+    its order, so the candidate latencies, the decisions and the returned
+    (x, D_m) are bit for bit those of ``greedy_spectrum``, at O(K)
+    plain-float work per step (numpy's per-call overhead loses at these
+    K).
+
+    Rows are built only for ``devices``, the devices of the plan, so the
+    table is (plan's devices x C) at any population size. Each table
+    built counts as ``spectrum_tables``, each greedy it serves as
+    ``spectrum_table_calls``."""
+
+    def __init__(self, v: int, devices: Sequence[int], net: NetworkState,
+                 ncfg: NetworkCfg, prof: CutProfile, B: int, L: int,
+                 C: Optional[int] = None):
+        self.C = ncfg.n_subcarriers if C is None else C
+        self.L = L
+        devs = np.unique(np.asarray(devices, dtype=np.int64))
+        pb = PartitionBatch(v, net, ncfg, prof, B, L, [len(devs)],
+                            devs[None, :])
+        x = np.arange(1, self.C + 1, dtype=np.float64)[:, None]
+        S, I, E = (t.T.tolist() for t in pb.phase_terms(x))
+        self._rows = dict(zip(devs.tolist(), zip(S, I, E)))
+        c = prof.at(v)
+        self._server = (B, c["gamma_sF"] + c["gamma_sB"],
+                        ncfg.f_server * ncfg.kappa)
+        telemetry.count("spectrum_tables")
+
+    def greedy(self, devices: Sequence[int]) -> Tuple[np.ndarray, float]:
+        """Alg. 3 for one cluster of the table's devices: (x, D_m), equal
+        to ``greedy_spectrum(v, devices, ..., C=self.C)``; D_m is a numpy
+        float64, as there, so a caller's sums keep their type (a trainer
+        hands its running total to ``jnp.asarray``, where a Python float
+        would be weakly typed and compile its save again)."""
+        K = len(devices)
+        assert self.C >= K, "need at least one subcarrier per device"
+        telemetry.count("spectrum_table_calls")
+        S, I, E = zip(*[self._rows[d] for d in devices])
+        B, gamma_s, f_s = self._server
+        tau_e = float(K * B * gamma_s / f_s)             # (18)
+        w = self.L - 1
+        n = [0] * K                      # x - 1: the column of each row
+        s, i, e = [r[0] for r in S], [r[0] for r in I], [r[0] for r in E]
+        cur = (max(s) + tau_e) + w * (max(i) + tau_e) + max(e)
+        for _ in range(self.C - K):
+            s1, ks, s2 = _top2(s)
+            i1, ki, i2 = _top2(i)
+            e1, ke, e2 = _top2(e)
+            held = sorted({ks, ki, ke})
+            bk = 0                       # the first device that holds none
+            while bk in held:
+                bk += 1
+            best = cur if bk < K else math.inf
+            for k in held:
+                c = n[k] + 1
+                lat = (max(s2 if k == ks else s1, S[k][c]) + tau_e) \
+                    + w * (max(i2 if k == ki else i1, I[k][c]) + tau_e) \
+                    + max(e2 if k == ke else e1, E[k][c])
+                if lat < best or (lat == best and k < bk):
+                    best, bk = lat, k
+            c = n[bk] = n[bk] + 1
+            s[bk], i[bk], e[bk] = S[bk][c], I[bk][c], E[bk][c]
+            cur = best
+        return np.asarray(n, dtype=np.int64) + 1, np.float64(cur)
 
 
 def greedy_spectrum_topk(v: int, devices: Sequence[int], net: NetworkState,
@@ -128,19 +224,31 @@ def brute_force_spectrum(v, devices, net, ncfg, prof, B, L,
 # Alg. 4 — Gibbs-sampling joint clustering + spectrum allocation
 # --------------------------------------------------------------------------
 
-def _round_latency_cached(v, clusters, net, ncfg, prof, B, L, cache,
-                          spectrum_fn=None):
+def _alg3(v, clusters, net, ncfg, prof, B, L, spectrum_fn=None):
+    """Alg. 3 for a plan over the devices of ``clusters``, as a function of
+    a cluster's sorted devices: ``spectrum_fn`` where one is given, else
+    the ``greedy`` of a :class:`SpectrumTable` of those devices, whose
+    build time counts in ``spectrum_s``."""
+    if spectrum_fn is not None:
+        return lambda key: spectrum_fn(v, list(key), net, ncfg, prof, B, L)
+    t0 = time.perf_counter()
+    table = SpectrumTable(v, [d for ds in clusters for d in ds], net, ncfg,
+                          prof, B, L)
+    telemetry.count("spectrum_s", time.perf_counter() - t0)
+    return table.greedy
+
+
+def _round_latency_cached(clusters, cache, alg3):
     """Round latency and spectrum of ``clusters``; a cluster not in
-    ``cache`` costs one Alg. 3 call (counted as ``spectrum_calls``, its
+    ``cache`` costs one ``alg3`` call (counted as ``spectrum_calls``, its
     seconds as ``spectrum_s``)."""
-    spectrum_fn = spectrum_fn or greedy_spectrum
     total = 0.0
     xs = []
     for ds in clusters:
         key = tuple(sorted(ds))
         if key not in cache:
             t0 = time.perf_counter()
-            cache[key] = spectrum_fn(v, list(key), net, ncfg, prof, B, L)
+            cache[key] = alg3(key)
             telemetry.count("spectrum_s", time.perf_counter() - t0)
             telemetry.count("spectrum_calls")
         x, lat = cache[key]
@@ -163,8 +271,10 @@ def gibbs_clustering(v: int, net: NetworkState, ncfg: NetworkCfg,
     ``sizes`` (optional) partitions the N devices into clusters of the
     given (possibly unequal) sizes instead of ``n_clusters`` equal chunks
     of ``cluster_size`` — needed under churn, where N is not always M*K.
-    ``spectrum_fn`` swaps in an alternative Alg. 3 implementation (e.g.
-    the vectorized ``repro.sim.batched.greedy_spectrum_batched``).
+    Alg. 3 runs on one :class:`SpectrumTable` of the plan's devices, built
+    before the first proposal; ``spectrum_fn`` swaps in another
+    implementation (e.g. the scalar reference ``greedy_spectrum``) and
+    builds no table.
 
     ``draws = (init_key, prop_u)`` replaces the internal RNG with
     pre-drawn randomness so an external (e.g. in-jit) mirror can share
@@ -195,8 +305,8 @@ def gibbs_clustering(v: int, net: NetworkState, ncfg: NetworkCfg,
         clusters = [list(order[m * cluster_size:(m + 1) * cluster_size])
                     for m in range(n_clusters)]
     cache: dict = {}
-    cur, xs = _round_latency_cached(v, clusters, net, ncfg, prof, B, L, cache,
-                                    spectrum_fn)
+    alg3 = _alg3(v, clusters, net, ncfg, prof, B, L, spectrum_fn)
+    cur, xs = _round_latency_cached(clusters, cache, alg3)
     best = (cur, [list(c) for c in clusters], [x.copy() for x in xs])
     hist = [cur]
     if n_clusters < 2:
@@ -219,8 +329,7 @@ def gibbs_clustering(v: int, net: NetworkState, ncfg: NetworkCfg,
             j = rng.integers(len(clusters[mp]))
         cand = [list(c) for c in clusters]
         cand[m][i], cand[mp][j] = cand[mp][j], cand[m][i]
-        new, new_xs = _round_latency_cached(v, cand, net, ncfg, prof, B, L,
-                                            cache, spectrum_fn)
+        new, new_xs = _round_latency_cached(cand, cache, alg3)
         eps = 1.0 / (1.0 + math.exp(min((new - cur) / max(delta, 1e-12),
                                         700.0)))
         accept_u = rng.random() if draws is None else float(prop_u[it][4])
@@ -258,8 +367,8 @@ def heuristic_clustering(v, net, ncfg, prof, B, L, n_clusters, cluster_size,
     clusters = [list(order[m * cluster_size:(m + 1) * cluster_size])
                 for m in range(n_clusters)]
     if optimize_spectrum:
-        lat, xs = _round_latency_cached(v, clusters, net, ncfg, prof, B, L,
-                                        {})
+        lat, xs = _round_latency_cached(
+            clusters, {}, _alg3(v, clusters, net, ncfg, prof, B, L))
     else:
         xs = _uniform_xs(clusters, ncfg)
         lat = round_latency(v, clusters, xs, net, ncfg, prof, B, L)
@@ -274,8 +383,8 @@ def random_clustering(v, net, ncfg, prof, B, L, n_clusters, cluster_size,
     clusters = [list(order[m * cluster_size:(m + 1) * cluster_size])
                 for m in range(n_clusters)]
     if optimize_spectrum:
-        lat, xs = _round_latency_cached(v, clusters, net, ncfg, prof, B, L,
-                                        {})
+        lat, xs = _round_latency_cached(
+            clusters, {}, _alg3(v, clusters, net, ncfg, prof, B, L))
     else:
         xs = _uniform_xs(clusters, ncfg)
         lat = round_latency(v, clusters, xs, net, ncfg, prof, B, L)

@@ -1,8 +1,9 @@
 """Vectorized + replicated candidate evaluation for Algs. 2/3/4.
 
-The looped implementations in ``core.resource`` call the scalar
-``cluster_latency`` once per candidate, each call re-deriving the
-cut-dependent constants. ``core.latency.BatchedClusterEvaluator``
+The scalar reference ``core.resource.greedy_spectrum`` calls
+``cluster_latency`` once per candidate; ``core.resource.SpectrumTable``,
+which ``greedy_spectrum_batched`` below runs, prices the same candidates
+from a table of device phase terms. ``core.latency.BatchedClusterEvaluator``
 (re-exported here) hoists everything x-independent and scores whole
 (P, K) candidate batches with a handful of numpy broadcasts — with a
 bit-exactness contract to the scalar path, so the greedy/Gibbs
@@ -54,25 +55,12 @@ def greedy_spectrum_batched(v: int, devices: Sequence[int],
                             prof: CutProfile, B: int, L: int,
                             C: Optional[int] = None
                             ) -> Tuple[np.ndarray, float]:
-    """Drop-in replacement for ``core.resource.greedy_spectrum``: identical
-    decisions (bit-identical candidate latencies, same argmin tie-breaks),
-    but each greedy step scores all K candidates in one broadcast instead
-    of K scalar ``cluster_latency`` calls."""
-    C = ncfg.n_subcarriers if C is None else C
-    K = len(devices)
-    assert C >= K, "need at least one subcarrier per device"
-    ev = BatchedClusterEvaluator(v, devices, net, ncfg, prof, B, L)
-    x = np.ones(K, dtype=np.int64)
-    cur = float(ev.latencies(x)[0])
-    if C == K:
-        return x, cur
-    eye = np.eye(K, dtype=np.int64)
-    for _ in range(C - K):
-        cands = ev.latencies(x[None, :] + eye)
-        best_k = int(np.argmin(cands))
-        x[best_k] += 1
-        cur = float(cands[best_k])
-    return x, cur
+    """Drop-in replacement for ``core.resource.greedy_spectrum`` with
+    identical decisions: Alg. 3 on a ``core.resource.SpectrumTable`` of
+    this call's K devices (bit-identical candidate latencies, same argmin
+    tie-breaks)."""
+    return rs.SpectrumTable(v, devices, net, ncfg, prof, B, L,
+                            C=C).greedy(devices)
 
 
 def gibbs_clustering_batched(*args, **kw):

@@ -230,6 +230,81 @@ def test_gibbs_uneven_sizes_partition():
         assert x.sum() == ncfg.n_subcarriers
 
 
+@pytest.mark.parametrize("L", [1, 2])
+@pytest.mark.parametrize("C_of_K", ["K", "K+1", "30"])
+@pytest.mark.parametrize("K", [1, 2, 5, 8])
+def test_spectrum_table_bit_identical_to_greedy(K, C_of_K, L):
+    """The table path's (x, D_m) equal the scalar Alg. 3's, float for
+    float, at every cut of the LeNet profile, over random networks and
+    device orders; C = K (one subcarrier each) and C = K + 1 (one step)
+    are the edges. The last two networks repeat devices, so candidates
+    tie and the first-index tie-break decides."""
+    prof = pf.lenet_profile()
+    C = {"K": K, "K+1": K + 1, "30": 30}[C_of_K]
+    ncfg = NetworkCfg(n_devices=10, n_subcarriers=C)
+    rng = np.random.default_rng([K, C, L])
+    nets = [sample_network(ncfg, *device_means(ncfg, int(rng.integers(99))),
+                           rng) for _ in range(3)]
+    nets += [NetworkState(f=net.f[idx], rate=net.rate[idx]) for net, idx in
+             ((nets[0], np.zeros(10, int)), (nets[1], np.arange(10) // 2))]
+    for net in nets:
+        devs = [int(d) for d in rng.choice(10, K, replace=False)]
+        table = rs.SpectrumTable(1, devs, net, ncfg, prof, 16, L)
+        for v in range(1, prof.n_cuts + 1):
+            if v > 1:
+                table = rs.SpectrumTable(v, devs, net, ncfg, prof, 16, L)
+            x, lat = table.greedy(devs)
+            xr, latr = rs.greedy_spectrum(v, devs, net, ncfg, prof, 16, L)
+            np.testing.assert_array_equal(x, xr)
+            assert lat == latr
+
+
+@pytest.mark.parametrize("n,M,K,sizes,L", [
+    (12, 4, 3, None, 1), (7, 3, 3, [3, 2, 2], 2), (11, 3, 4, [4, 4, 3], 1),
+    (10, 2, 4, None, 2), (30, 6, 5, None, 1)])
+def test_gibbs_default_table_matches_scalar_greedy(n, M, K, sizes, L):
+    """Gibbs on the table path (the default) against Gibbs on the scalar
+    Alg. 3: the same clusters, xs and latency, with uneven ``sizes`` and
+    with devices left out of the plan (M * K < n)."""
+    prof = pf.lenet_profile()
+    ncfg = NetworkCfg(n_devices=n, n_subcarriers=30)
+    net = sample_network(ncfg, *device_means(ncfg, n),
+                         np.random.default_rng(n + L))
+    kw = dict(iters=120, seed=n, sizes=sizes, track=True)
+    got = rs.gibbs_clustering(1, net, ncfg, prof, 16, L, M, K, **kw)
+    want = rs.gibbs_clustering(1, net, ncfg, prof, 16, L, M, K,
+                               spectrum_fn=rs.greedy_spectrum, **kw)
+    assert got[0] == want[0] and got[2] == want[2] and got[3] == want[3]
+    for x, xr in zip(got[1], want[1]):
+        np.testing.assert_array_equal(x, xr)
+
+
+def test_saa_and_baselines_on_table_path_match_scalar_greedy():
+    """SAA (Alg. 2 over Alg. 4) and the baselines that optimise spectrum
+    take the table path with the scalar greedy's results."""
+    prof = pf.lenet_profile()
+    ncfg = NetworkCfg(n_devices=6, n_subcarriers=12)
+    kw = dict(n_clusters=2, cluster_size=3, n_samples=2, gibbs_iters=20,
+              seed=4, cuts=[1, 4, 9])
+    v, means = rs.saa_cut_selection(prof, ncfg, 16, 1, **kw)
+    vr, means_r = rs.saa_cut_selection(prof, ncfg, 16, 1,
+                                       spectrum_fn=rs.greedy_spectrum, **kw)
+    assert v == vr
+    np.testing.assert_array_equal(means, means_r)
+    net = _net(6, seed=3)
+    for plan in (rs.heuristic_clustering, rs.random_clustering):
+        cl, xs, lat = plan(2, net, ncfg, prof, 16, 2, 2, 3,
+                           optimize_spectrum=True)
+        total = 0.0
+        for c, x in zip(cl, xs):
+            xr, latr = rs.greedy_spectrum(2, sorted(c), net, ncfg, prof, 16,
+                                          2)
+            np.testing.assert_array_equal(
+                x, xr[np.argsort(np.argsort(c))])
+            total += latr
+        assert lat == total
+
+
 def test_equal_split_x_budget():
     """Feasible split summing to exactly C, remainder to the leading
     devices; K > C is infeasible and must raise."""

@@ -137,10 +137,11 @@ def test_evaluator_bit_identical_to_scalar():
     np.testing.assert_array_equal(ev.latencies(xs), want)
 
 
-@pytest.mark.parametrize("seed", [0, 3, 17])
-def test_batched_greedy_identical_decisions(seed):
-    net, ncfg = _net(5, seed=seed)
-    args = (1, list(range(5)), net, ncfg, PROF, 16, 1)
+@pytest.mark.parametrize("seed,K,L", [(0, 5, 1), (3, 5, 1), (17, 5, 1),
+                                      (4, 1, 1), (5, 2, 2), (6, 8, 2)])
+def test_batched_greedy_identical_decisions(seed, K, L):
+    net, ncfg = _net(max(K, 5), seed=seed)
+    args = (1, list(range(K)), net, ncfg, PROF, 16, L)
     xg, lg = rs.greedy_spectrum(*args)
     xb, lb = greedy_spectrum_batched(*args)
     np.testing.assert_array_equal(xg, xb)

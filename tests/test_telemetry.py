@@ -286,6 +286,8 @@ def test_trainer_round_records_phases_and_counts(tmp_path):
                             tr.cpsl.ccfg.batch_per_device, L, M, K,
                             iters=30, seed=5 + rnd, spectrum_fn=spectrum)
         assert counts["spectrum_calls"] == len(misses) > 0
+        assert counts["spectrum_table_calls"] == counts["spectrum_calls"]
+        assert counts["spectrum_tables"] == 1
         assert counts["spectrum_s"] > 0
         assert set(phase) >= {"round", "plan", "network", "cluster",
                               "gather", "step", "fedavg", "sync"}
@@ -324,3 +326,27 @@ def test_fused_round_records_one_sync(tmp_path):
         assert "dispatches" not in h["counts"]
         assert h["phase_s"]["sync"] + h["phase_s"]["plan"] \
             <= h["phase_s"]["round"] <= h["wall_s"]
+
+
+@pytest.mark.parametrize("custom", [False, True])
+def test_spectrum_table_counters(custom):
+    """One recorded Gibbs plan builds one table, which serves every Alg. 3
+    call; a custom ``spectrum_fn`` builds none and is served by none."""
+    from repro import telemetry
+    from repro.core import resource as rs
+    from repro.core.channel import device_means, sample_network
+    ncfg = NetworkCfg(n_devices=12, n_subcarriers=24)
+    net = sample_network(ncfg, *device_means(ncfg, 3),
+                         np.random.default_rng(3))
+    telemetry.begin_round(0)
+    rs.gibbs_clustering(1, net, ncfg, lenet_profile(), 16, 1, 4, 3,
+                        iters=40, seed=1,
+                        spectrum_fn=rs.greedy_spectrum if custom else None)
+    _, counts = telemetry.fold()
+    assert counts["spectrum_calls"] > 4
+    if custom:
+        assert counts.get("spectrum_table_calls", 0) == 0
+        assert counts.get("spectrum_tables", 0) == 0
+    else:
+        assert counts["spectrum_table_calls"] == counts["spectrum_calls"]
+        assert counts["spectrum_tables"] == 1
