@@ -15,13 +15,21 @@ from typing import Optional, Tuple
 
 @dataclass(frozen=True)
 class MoECfg:
-    n_experts: int
+    n_experts: int                  # routed experts the router scores
     top_k: int
     d_ff_expert: int
     n_shared_experts: int = 0
-    group_size: int = 2048          # tokens per dispatch group (GShard-style)
-    capacity_factor: float = 1.25
-    router_aux_weight: float = 0.01
+    n_held: int = 0                 # routed experts held here, the
+                                    # first n_held (0 = all): one chip's
+                                    # share under expert parallelism
+    norm_topk_prob: bool = True     # renormalise the top-k gate weights
+    balance_loss: str = "switch"    # switch (over the batch) | seq
+                                    # (DeepSeek's, per sequence)
+    router_aux_weight: float = 0.01  # the balance loss's alpha
+
+    @property
+    def held(self) -> int:
+        return self.n_held or self.n_experts
 
 
 @dataclass(frozen=True)
@@ -31,6 +39,20 @@ class MLACfg:
     qk_nope_head_dim: int = 128
     qk_rope_head_dim: int = 64
     v_head_dim: int = 128
+
+
+@dataclass(frozen=True)
+class YaRNCfg:
+    """YaRN rope scaling (arXiv:2309.00071) as DeepSeek-V2 applies it:
+    frequencies ramped between extrapolation and interpolation by
+    ``factor``, and the attention's softmax scale multiplied by
+    ``yarn_mscale(factor, mscale_all_dim)`` squared."""
+    factor: float
+    original_max_position: int
+    beta_fast: float = 32.0
+    beta_slow: float = 1.0
+    mscale: float = 1.0
+    mscale_all_dim: float = 0.0
 
 
 @dataclass(frozen=True)
@@ -75,6 +97,7 @@ class ModelConfig:
     qkv_bias: bool = False
     qk_norm: bool = False
     rope_theta: float = 1e4
+    rope_scaling: Optional[YaRNCfg] = None
     attn_softcap: float = 0.0        # gemma2: 50.0
     final_softcap: float = 0.0       # gemma2: 30.0
     norm_kind: str = "rmsnorm"       # rmsnorm | layernorm

@@ -4,7 +4,7 @@ d_ff=14336, vocab=65536. [arXiv:2403.19887; hf]
 
 Note: Jamba v0.1 uses Mamba-1 internally; this framework uses the Mamba-2
 SSD block (d_state=16 as in Jamba) — the TPU-native choice (chunked SSD maps
-onto the MXU; see DESIGN.md hardware-adaptation notes).
+onto the MXU).
 """
 from repro.configs.base import LayerSpec, MoECfg, ModelConfig, SSMCfg
 
@@ -20,7 +20,7 @@ def config() -> ModelConfig:
         d_model=4096, n_layers=32, n_heads=32, n_kv_heads=8, head_dim=128,
         d_ff=14336, vocab_size=65536,
         pattern=period,
-        moe=MoECfg(n_experts=16, top_k=2, d_ff_expert=14336, group_size=512),
+        moe=MoECfg(n_experts=16, top_k=2, d_ff_expert=14336),
         ssm=SSMCfg(d_state=16, d_conv=4, expand=2, headdim=64, ngroups=1,
                    chunk_size=256),
         tie_embeddings=False,

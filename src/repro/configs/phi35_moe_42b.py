@@ -10,6 +10,6 @@ def config() -> ModelConfig:
         d_model=4096, n_layers=32, n_heads=32, n_kv_heads=8, head_dim=128,
         d_ff=6400, vocab_size=32064,
         pattern=(LayerSpec("attn", "moe"),),
-        moe=MoECfg(n_experts=16, top_k=2, d_ff_expert=6400, group_size=512),
+        moe=MoECfg(n_experts=16, top_k=2, d_ff_expert=6400),
         tie_embeddings=False, rope_theta=1e4,
     )

@@ -6,7 +6,7 @@ The 4 shape cells (assignment):
     prefill_32k: seq 32768,  global_batch 32   -> prefill_step
     decode_32k:  seq 32768,  global_batch 128  -> serve_step (1 new token)
     long_500k:   seq 524288, global_batch 1    -> serve_step; only for
-                 sub-quadratic archs (mamba2, jamba) — see DESIGN.md.
+                 sub-quadratic archs (mamba2, jamba; ``LONG_CTX_ARCHS``).
 """
 from __future__ import annotations
 
@@ -26,6 +26,7 @@ ARCHS = {
     "whisper-small": whisper_small.config,
     "chameleon-34b": chameleon_34b.config,
     "deepseek-v2-lite-16b": deepseek_v2_lite_16b.config,
+    "deepseek-v2-lite-16b-ep8": deepseek_v2_lite_16b.config_ep8,
     "phi3.5-moe-42b-a6.6b": phi35_moe_42b.config,
     "mamba2-2.7b": mamba2_2p7b.config,
     "jamba-v0.1-52b": jamba_v01_52b.config,
@@ -105,10 +106,11 @@ def reduce_for_smoke(cfg: ModelConfig) -> ModelConfig:
         q_chunk=8, kv_chunk=8,
     )
     if cfg.moe is not None:
-        # ample capacity: smoke tests check exact equivalences (no drops)
-        kw["moe"] = dataclasses.replace(cfg.moe, n_experts=4, top_k=2,
-                                        d_ff_expert=32, group_size=16,
-                                        capacity_factor=8.0)
+        # a held share stays a share: 2 of 8 experts here
+        share = cfg.moe.held < cfg.moe.n_experts
+        kw["moe"] = dataclasses.replace(cfg.moe, n_experts=8 if share else 4,
+                                        top_k=2, d_ff_expert=32,
+                                        n_held=2 if share else 0)
     if cfg.mla is not None:
         kw["mla"] = MLACfg(kv_lora_rank=32, q_lora_rank=0,
                            qk_nope_head_dim=16, qk_rope_head_dim=8,

@@ -62,10 +62,17 @@ from repro.configs.base import CPSLConfig
 from repro.core import compression as cmp
 from repro.core import partitioning as pt
 from repro.core.splitting import SplitModel
+from repro.models import common as cm
 
 
 def _flat(tree):
     return jax.tree.map(lambda t: t.reshape((-1,) + t.shape[2:]), tree)
+
+
+def _step_metrics(loss, aux: dict) -> dict:
+    """A step's metrics: the objective's main ``loss``, the auxiliary
+    loss as ``aux``, and the model's counters (``moe_routed``...)."""
+    return dict(aux, loss=loss, aux=aux["loss"])
 
 
 class CPSL:
@@ -123,7 +130,7 @@ class CPSL:
                 jax.tree.map(lambda t: t[k], dev),
                 jax.tree.map(lambda t: t[k], batch)) for k in range(K)]
         return (jnp.stack([o[0] for o in outs]),
-                jnp.stack([o[1] for o in outs]))
+                jax.tree.map(lambda *t: jnp.stack(t), *[o[1] for o in outs]))
 
     def _total_loss(self, dev, srv, batch):
         """batch leaves: (K, B, ...). Returns (scalar, metrics)."""
@@ -144,13 +151,13 @@ class CPSL:
                                                                     batch)
             # eq. (5): concatenate client smashed data into the server batch
             smashed = smashed.reshape((-1,) + smashed.shape[2:])
-            aux_d = aux_d.mean()
+            aux_d = cm.aux_over_clients(aux_d)
             flat = _flat(batch)
         smashed = pt.shard(smashed, "batch")
         with jax.named_scope("server_side"):
             loss, aux_s = self.split.server_loss(srv, smashed, flat)
-        total = loss + aux_d + aux_s
-        return total, {"loss": loss, "aux": aux_d + aux_s}
+        aux = cm.aux_add(aux_d, aux_s)
+        return loss + aux["loss"], _step_metrics(loss, aux)
 
     # -- fused step ----------------------------------------------------------
 
@@ -175,20 +182,21 @@ class CPSL:
                               + t.shape[2:]), 1, 0), batch)
 
             def acc(carry, mbatch):
-                g_dev, g_srv, loss, aux = carry
+                g_dev, g_srv, mt_acc = carry
                 (_, mt), (gd, gs) = grad_fn(state["dev"], state["srv"],
                                             mbatch)
                 g_dev = jax.tree.map(lambda a, b: a + b / m, g_dev, gd)
                 g_srv = jax.tree.map(lambda a, b: a + b / m, g_srv, gs)
-                return (g_dev, g_srv, loss + mt["loss"] / m,
-                        aux + mt["aux"] / m), None
+                mt = dict(mt, loss=mt["loss"] / m, aux=mt["aux"] / m)
+                return (g_dev, g_srv, cm.aux_add(mt_acc, mt)), None
 
             zeros = lambda t: jax.tree.map(  # noqa: E731
                 lambda p: jnp.zeros(p.shape, jnp.float32), t)
-            (g_dev, g_srv, loss, aux), _ = jax.lax.scan(
+            mt0 = jax.eval_shape(grad_fn, state["dev"], state["srv"],
+                                 jax.tree.map(lambda t: t[0], mb))[0][1]
+            (g_dev, g_srv, metrics), _ = jax.lax.scan(
                 acc, (zeros(state["dev"]), zeros(state["srv"]),
-                      jnp.zeros(()), jnp.zeros(())), mb)
-            metrics = {"loss": loss, "aux": aux}
+                      zeros(mt0)), mb)
         else:
             (_, metrics), (g_dev, g_srv) = grad_fn(state["dev"],
                                                    state["srv"], batch)
@@ -233,9 +241,9 @@ class CPSL:
         def srv_loss(srv, sm):
             with jax.named_scope("server_side"):
                 loss, aux = split.server_loss(srv, sm, flat)
-            return loss + aux, loss
+            return loss + aux["loss"], _step_metrics(loss, aux)
 
-        (_, loss), (g_srv, g_smashed) = jax.value_and_grad(
+        (_, metrics), (g_srv, g_smashed) = jax.value_and_grad(
             srv_loss, argnums=(0, 1), has_aux=True)(state["srv"],
                                                     smashed_flat)
         with jax.named_scope("update"):
@@ -267,7 +275,7 @@ class CPSL:
                                                  lr_scale=lr_scale)
         state = dict(state, dev=new_dev, dev_opt=dev_opt, srv=new_srv,
                      srv_opt=srv_opt, step=state["step"] + 1)
-        return state, {"loss": loss, "aux": jnp.zeros(())}
+        return state, metrics
 
     @functools.partial(jax.jit, static_argnums=0)
     def _protocol_step(self, state, batch):
@@ -351,10 +359,15 @@ class CPSL:
                     state, None if data_sizes is None else data_sizes[m])
             telemetry.count("dispatches")
         loss = jnp.mean(jnp.stack([m["loss"] for m in metrics]))
+        counters = functools.reduce(cm.aux_add, [
+            {k: v for k, v in mt.items() if k not in ("loss", "aux")}
+            for mt in metrics])
         with telemetry.span("sync"):
-            loss = float(loss)
+            loss, counters = jax.device_get((loss, counters))
         telemetry.count("syncs")
-        return state, {"loss": loss}
+        for name, n in counters.items():
+            telemetry.count(name, float(n))
+        return state, {"loss": float(loss)}
 
     # -- fused round (single donated jit over the (M, L) grid) ---------------
 

@@ -5,10 +5,12 @@ A ``SplitModel`` bundles:
     init_device(key) / init_server(key)
     device_apply(dev_params, batch)        -> (smashed, aux)
     server_loss(srv_params, smashed, batch)-> (loss, aux)
+    (aux: a dict of the auxiliary loss ``"loss"``, added to the objective,
+    and any counters, laid out as ``models.common.aux_zero``)
     export(dev_params, srv_params)         -> assembled params (+cfg) for
                                               standard serving/eval.
 
-Cut-layer conventions per family (see DESIGN.md §Arch-applicability):
+Cut-layer conventions per family:
   - LM (dense/moe/ssm/hybrid/vlm): device = embed + blocks[:v];
     server = blocks[v:] + final norm + (untied) head. Tied-embedding archs
     are trained with an untied server-side head under CPSL (the device owns
@@ -183,7 +185,7 @@ def make_encdec_split(cfg: ModelConfig, v: int) -> SplitModel:
             return whp.enc_block_apply(p, x, cfg), None
 
         x, _ = jax.lax.scan(body, x, dev["enc_stack"])
-        return x, jnp.zeros((), jnp.float32)
+        return x, {"loss": jnp.zeros((), jnp.float32)}
 
     def server_loss(srv, smashed, batch):
         def body(x, p):
@@ -196,7 +198,7 @@ def make_encdec_split(cfg: ModelConfig, v: int) -> SplitModel:
                 else srv["embed"]["head"])
         loss = cm.lm_head_loss(head, xd, batch["labels"], cfg,
                                batch.get("mask"))
-        return loss, jnp.zeros((), jnp.float32)
+        return loss, {"loss": jnp.zeros((), jnp.float32)}
 
     def export(dev, srv):
         params = dict(srv)
@@ -231,7 +233,7 @@ def make_lenet_split(v: int, input_hw: int = 28,
 
     def device_apply(dev, batch):
         return (ln.apply_range(dev, batch["image"], 0, v, conv_impl),
-                jnp.zeros((), jnp.float32))
+                {"loss": jnp.zeros((), jnp.float32)})
 
     def server_loss(srv, smashed, batch):
         logits = ln.apply_range(srv, smashed, v, ln.N_LAYERS, conv_impl)
@@ -239,12 +241,12 @@ def make_lenet_split(v: int, input_hw: int = 28,
         nll = -jnp.take_along_axis(logp, batch["label"][:, None], axis=-1)
         weight = batch.get("sample_weight")
         if weight is None:
-            return jnp.mean(nll), jnp.zeros((), jnp.float32)
+            return jnp.mean(nll), {"loss": jnp.zeros((), jnp.float32)}
         # padded client slots (fleet layout masks): masked rows carry
         # exactly zero weight, so their data never reaches loss or grads
         w = weight.reshape(-1).astype(nll.dtype)
         loss = (nll[:, 0] * w).sum() / jnp.maximum(w.sum(), 1.0)
-        return loss, jnp.zeros((), jnp.float32)
+        return loss, {"loss": jnp.zeros((), jnp.float32)}
 
     def export(dev, srv):
         return ln.merge_params(dev, srv), None

@@ -50,13 +50,13 @@ from repro.models import whisper as whp
 
 # realistic default cut layers (shallow per the paper's POOL1 finding;
 # below the first MoE block where one exists so expert banks stay
-# server-side — see DESIGN.md §Arch-applicability)
+# server-side)
 DEFAULT_CUTS = {
     "deepseek-v2-lite-16b": 1, "phi3.5-moe-42b-a6.6b": 1,
     "jamba-v0.1-52b": 1, "whisper-small": 2,
 }
 
-# grad-accumulation splits. MEASURED NOTE (EXPERIMENTS.md §Perf): with the
+# grad-accumulation splits. Measured: with the
 # fsdp profile at global_batch 256 == chip count, m=2 drops the per-step
 # batch BELOW the chip count, the 'model' axis falls out of the batch
 # sharding, and activations replicate 16x (compute term x15). Microbatching
@@ -232,10 +232,11 @@ def build_train(cfg: ModelConfig, shape: ShapeCfg, mesh, cut: int,
         batch_shapes["tokens"] = sds((K, B, shape.seq_len), jnp.int32)
     st_sh = state_shardings(state_shapes, mesh)
     b_sh = batch_shardings(batch_shapes, mesh)
-    m_sh = {"loss": NamedSharding(mesh, P()), "aux": NamedSharding(mesh, P())}
-
     step_impl = (cpsl.fused_step_impl if ccfg.fused_step
                  else cpsl.protocol_step_impl)
+    m_sh = jax.tree.map(lambda _: NamedSharding(mesh, P()),
+                        jax.eval_shape(step_impl, state_shapes,
+                                       batch_shapes)[1])
 
     def step(state, batch):
         return step_impl(state, batch)

@@ -1,5 +1,6 @@
-"""Common pure-JAX model components: norms, rope, attention (GQA/MLA,
-naive/chunked flash-equivalent), MLPs, GShard-style MoE.
+"""Common pure-JAX model components: norms, rope (with YaRN), attention
+(GQA/MLA, naive/chunked flash-equivalent), MLPs, dropless MoE over the
+experts held here.
 
 Everything is functional: ``*_init(key, ...) -> params`` (nested dicts of
 f32 arrays) and ``*_apply(params, x, ...) -> y``. Compute runs in the
@@ -15,7 +16,7 @@ import jax
 import jax.numpy as jnp
 from jax import lax
 
-from repro.configs.base import ModelConfig, MoECfg, MLACfg
+from repro.configs.base import ModelConfig, MoECfg, MLACfg, YaRNCfg
 from repro.core import partitioning as pt
 
 Params = dict
@@ -86,17 +87,44 @@ def apply_norm(p: Params, x: jnp.ndarray, kind: str, eps: float = 1e-6,
 # rotary position embeddings (NeoX half-rotation convention)
 # --------------------------------------------------------------------------
 
-def rope_freqs(dim: int, theta: float) -> jnp.ndarray:
-    return 1.0 / (theta ** (jnp.arange(0, dim, 2, dtype=jnp.float32) / dim))
+def yarn_mscale(factor: float, mscale: float) -> float:
+    return 0.1 * mscale * math.log(factor) + 1.0 if factor > 1 else 1.0
 
 
-def apply_rope(x: jnp.ndarray, positions: jnp.ndarray, theta: float) -> jnp.ndarray:
+def rope_freqs(dim: int, theta: float,
+               scaling: Optional[YaRNCfg] = None) -> jnp.ndarray:
+    """Rotary frequencies; with ``scaling``, YaRN's: frequencies below the
+    ``beta_slow`` correction dimension are interpolated by ``factor``,
+    those above ``beta_fast``'s kept, a linear ramp between."""
+    freqs = 1.0 / (theta ** (jnp.arange(0, dim, 2, dtype=jnp.float32) / dim))
+    if scaling is None:
+        return freqs
+
+    def corr(rotations):
+        return dim * math.log(scaling.original_max_position
+                              / (rotations * 2 * math.pi)) \
+            / (2 * math.log(theta))
+
+    low = max(math.floor(corr(scaling.beta_fast)), 0)
+    high = min(math.ceil(corr(scaling.beta_slow)), dim - 1)
+    ramp = jnp.clip((jnp.arange(dim // 2, dtype=jnp.float32) - low)
+                    / (high - low if high > low else 0.001), 0.0, 1.0)
+    return freqs * (1.0 - ramp) + (freqs / scaling.factor) * ramp
+
+
+def apply_rope(x: jnp.ndarray, positions: jnp.ndarray, theta: float,
+               scaling: Optional[YaRNCfg] = None) -> jnp.ndarray:
     """x: (..., S, H, D); positions: (S,) or broadcastable to (..., S)."""
     d = x.shape[-1]
-    freqs = rope_freqs(d, theta)                       # (d/2,)
+    freqs = rope_freqs(d, theta, scaling)              # (d/2,)
     angles = positions[..., :, None].astype(jnp.float32) * freqs  # (..., S, d/2)
     cos = jnp.cos(angles)[..., :, None, :]             # (..., S, 1, d/2)
     sin = jnp.sin(angles)[..., :, None, :]
+    if scaling is not None:
+        m = (yarn_mscale(scaling.factor, scaling.mscale)
+             / yarn_mscale(scaling.factor, scaling.mscale_all_dim))
+        if m != 1.0:
+            cos, sin = cos * m, sin * m
     x1, x2 = jnp.split(x.astype(jnp.float32), 2, axis=-1)
     y1 = x1 * cos - x2 * sin
     y2 = x2 * cos + x1 * sin
@@ -312,8 +340,7 @@ def shard_grouped_qkv(q, k, v):
     else:
         # heads don't divide the TP axis (e.g. 14 heads on 16-way TP):
         # replicate heads across TP, shard batch only. Wastes TP-axis
-        # compute on attention; see EXPERIMENTS.md §Perf for the
-        # head-padding iteration.
+        # compute on attention.
         q = pt.shard(q, "batch", None, None, None, None)
         k = pt.shard(k, "batch", None, None, None)
         v = pt.shard(v, "batch", None, None, None)
@@ -455,8 +482,17 @@ def mla_project_latent(p: Params, x: jnp.ndarray, cfg: ModelConfig,
     c_kv, k_rope = jnp.split(ckv_kr, [m.kv_lora_rank], axis=-1)
     c_kv = apply_norm(p["kv_norm"], c_kv, "rmsnorm", cfg.norm_eps)
     k_rope = apply_rope(k_rope[:, :, None, :], positions,
-                        cfg.rope_theta)[:, :, 0, :]
+                        cfg.rope_theta, cfg.rope_scaling)[:, :, 0, :]
     return c_kv, k_rope
+
+
+def mla_softmax_gain(cfg: ModelConfig) -> float:
+    """What the softmax scale 1/sqrt(qk head dim) is multiplied by: YaRN's
+    mscale(factor, mscale_all_dim) squared, as DeepSeek-V2 sets it."""
+    s = cfg.rope_scaling
+    if s is None or not s.mscale_all_dim:
+        return 1.0
+    return yarn_mscale(s.factor, s.mscale_all_dim) ** 2
 
 
 def mla_apply(p: Params, x: jnp.ndarray, cfg: ModelConfig, *,
@@ -469,6 +505,12 @@ def mla_apply(p: Params, x: jnp.ndarray, cfg: ModelConfig, *,
     into the query, W_UV folded into the output) — the memory-optimal decode
     path; scores/values touch only rank-r tensors.
     """
+    with jax.named_scope("mla"):
+        return _mla(p, x, cfg, causal, positions, latent, kv_valid_len,
+                    absorbed)
+
+
+def _mla(p, x, cfg, causal, positions, latent, kv_valid_len, absorbed):
     m: MLACfg = cfg.mla
     B, S, _ = x.shape
     H = cfg.n_heads
@@ -478,7 +520,7 @@ def mla_apply(p: Params, x: jnp.ndarray, cfg: ModelConfig, *,
         positions = jnp.arange(S)
     q = dense(p["wq"], x).reshape(B, S, H, dn + dr)
     q_nope, q_rope = jnp.split(q, [dn], axis=-1)
-    q_rope = apply_rope(q_rope, positions, cfg.rope_theta)
+    q_rope = apply_rope(q_rope, positions, cfg.rope_theta, cfg.rope_scaling)
     if latent is None:
         c_kv, k_rope = mla_project_latent(p, x, cfg, positions)
         q_offset = 0
@@ -486,6 +528,7 @@ def mla_apply(p: Params, x: jnp.ndarray, cfg: ModelConfig, *,
         c_kv, k_rope = latent
         q_offset = positions[0] if positions.ndim == 1 else 0
     Skv = c_kv.shape[1]
+    gain = mla_softmax_gain(cfg)
 
     if absorbed:
         # fold W_UK into q: q_lat (B,S,H,r); attend over latent directly.
@@ -494,7 +537,8 @@ def mla_apply(p: Params, x: jnp.ndarray, cfg: ModelConfig, *,
         qq = jnp.concatenate([q_lat, q_rope], axis=-1)     # (B,S,H,r+dr)
         kk = jnp.concatenate([c_kv, k_rope], axis=-1)      # (B,Skv,r+dr)
         # grouped layout with G=1 kv head of width r+dr, value = c_kv (r)
-        qq = qq.reshape(B, S, 1, H, r + dr) / math.sqrt((dn + dr) / (r + dr))
+        qq = qq.reshape(B, S, 1, H, r + dr) * (
+            gain / math.sqrt((dn + dr) / (r + dr)))
         qq = pt.shard(qq, "batch", None, None, "heads", None)
         kk = kk[:, :, None, :]
         vv = c_kv[:, :, None, :]
@@ -509,6 +553,8 @@ def mla_apply(p: Params, x: jnp.ndarray, cfg: ModelConfig, *,
             [k_nope, jnp.broadcast_to(k_rope[:, :, None, :], (B, Skv, H, dr))],
             axis=-1)
         qq = jnp.concatenate([q_nope, q_rope], axis=-1)
+        if gain != 1.0:
+            qq = qq * gain
         # full multi-head (G=H, R=1); pad v to qk width for the shared core
         o = grouped_attention(qq.reshape(B, S, H, 1, dn + dr), k,
                               jnp.pad(v, ((0, 0), (0, 0), (0, 0),
@@ -552,111 +598,184 @@ def mlp_apply(p: Params, x: jnp.ndarray, cfg: ModelConfig) -> jnp.ndarray:
 
 
 # --------------------------------------------------------------------------
-# GShard-style MoE with grouped dense dispatch
+# auxiliary outputs of a layer stack: the auxiliary loss and counters
+# --------------------------------------------------------------------------
+
+def aux_zero(cfg: ModelConfig) -> dict:
+    """A stack's auxiliary outputs before any layer: ``loss`` (added to the
+    objective), and for MoE models ``moe_routed`` (token choices that land
+    on the experts held here) and ``moe_load_max`` (the largest held
+    expert's token choices in one layer call)."""
+    z = jnp.zeros((), jnp.float32)
+    if cfg.moe is None:
+        return {"loss": z}
+    return {"loss": z, "moe_routed": z, "moe_load_max": z}
+
+
+def aux_add(a: dict, b: dict) -> dict:
+    """Two layers' (or steps') auxiliary outputs together: ``*_max``
+    counters by their maximum, everything else summed."""
+    return {k: jnp.maximum(a[k], b[k]) if k.endswith("_max") else a[k] + b[k]
+            for k in a}
+
+
+def aux_over_clients(aux: dict) -> dict:
+    """The K device-side models' outputs (leading axis K) as one: the loss
+    averaged (each device's batch is 1/K of the server's), counters
+    summed or maximised."""
+    return {k: (v.max() if k.endswith("_max") else
+                v.mean() if k == "loss" else v.sum()) for k, v in aux.items()}
+
+
+# --------------------------------------------------------------------------
+# MoE: the experts held here, dropless, through a grouped matmul
 # --------------------------------------------------------------------------
 
 def moe_init(key, cfg: ModelConfig) -> Params:
+    """The router scores all ``n_experts``; weights of the ``held`` ones."""
     m: MoECfg = cfg.moe
-    d, dff, E = cfg.d_model, m.d_ff_expert, m.n_experts
+    d, dff, E, H = cfg.d_model, m.d_ff_expert, m.n_experts, m.held
     dt = pdtype(cfg)
     ks = jax.random.split(key, 5)
     s_in, s_ff = 1.0 / math.sqrt(d), 1.0 / math.sqrt(dff)
     p = {
         "router": _normal(ks[0], (d, E), s_in, jnp.float32),
-        "w_gate": _normal(ks[1], (E, d, dff), s_in, dt),
-        "w_up": _normal(ks[2], (E, d, dff), s_in, dt),
-        "w_down": _normal(ks[3], (E, dff, d), s_ff, dt),
+        "w_gate": _normal(ks[1], (H, d, dff), s_in, dt),
+        "w_up": _normal(ks[2], (H, d, dff), s_in, dt),
+        "w_down": _normal(ks[3], (H, dff, d), s_ff, dt),
     }
     if m.n_shared_experts:
         p["shared"] = mlp_init(ks[4], d, dff * m.n_shared_experts, cfg)
     return p
 
 
-def moe_apply(p: Params, x: jnp.ndarray, cfg: ModelConfig,
-              no_drop: bool = False) -> Tuple[jnp.ndarray, jnp.ndarray]:
-    """x: (B, S, D) -> (y, aux_loss). Grouped dense dispatch:
+def moe_route(p: Params, x: jnp.ndarray, m: MoECfg):
+    """x: (T, D) -> (probs (T, E), gate weights (T, k), experts (T, k)).
+    Softmax over all experts, greedy top-k; the weights renormalised only
+    where the config says so (DeepSeek-V2-Lite's routed scaling factor is
+    1). The logits are float32 at full precision, as the published gates
+    are: a float32 dot at a TPU's default precision is one bfloat16 pass,
+    which flips near-tied choices."""
+    logits = jnp.dot(x.astype(jnp.float32), p["router"],
+                     precision=lax.Precision.HIGHEST)
+    probs = jax.nn.softmax(logits, axis=-1)
+    w, idx = lax.top_k(probs, m.top_k)
+    if m.norm_topk_prob:
+        w = w / jnp.maximum(w.sum(-1, keepdims=True), 1e-9)
+    return probs, w, idx
 
-    tokens are split into groups of ``group_size``; each group routes its
-    tokens into (E, C) capacity slots via one-hot dispatch/combine einsums
-    (SPMD-friendly: no scatter, lowers to all-to-all-class collectives when
-    the expert axis is sharded). Overflow tokens are dropped (capacity
-    factor 1.25), matching GShard/Switch semantics.
-    """
+
+def moe_balance_loss(probs, idx, m: MoECfg, n_seq: int) -> jnp.ndarray:
+    """``switch``: E * sum_e f_e p_e over the whole batch (f_e the share of
+    token choices, p_e the mean probability); ``seq`` (DeepSeek-V2): per
+    sequence, f_i = count_i E / (S k), P_i = mean_t s_it, the loss the
+    mean over sequences of sum_i f_i P_i. Both times alpha."""
+    E, k = m.n_experts, m.top_k
+    probs = probs.reshape(n_seq, -1, E)
+    S = probs.shape[1]
+    counts = jax.nn.one_hot(idx.reshape(n_seq, S * k), E,
+                            dtype=jnp.float32).sum(1)       # (n_seq, E)
+    if m.balance_loss == "seq":
+        f = counts * E / (S * k)
+        return m.router_aux_weight * jnp.mean(
+            jnp.sum(f * probs.mean(1), -1))
+    f = counts.sum(0) / (n_seq * S * k)
+    return m.router_aux_weight * E * jnp.sum(f * probs.mean((0, 1)))
+
+
+@jax.custom_vjp
+def _permute(x, order, inverse):
+    """x[order], whose gradient is the gather g[inverse] (no scatter)."""
+    return x[order]
+
+
+_permute.defvjp(lambda x, order, inverse: (x[order], inverse),
+                lambda inverse, g: (g[inverse], None, None))
+
+
+def _rows_in_groups(y, sizes):
+    return jnp.where((jnp.arange(y.shape[0]) < sizes.sum())[:, None], y, 0)
+
+
+@jax.custom_vjp
+def _gmm(lhs, rhs, sizes):
+    """Grouped matmul: rows [0, sum(sizes)) of ``lhs`` times their group's
+    matrix of ``rhs``; the rows after are zero, and so is their gradient.
+    The TPU's ragged-dot kernel leaves rows outside every group unwritten,
+    in the forward and in the backward's product for ``lhs``."""
+    return _rows_in_groups(lax.ragged_dot(lhs, rhs, sizes), sizes)
+
+
+def _gmm_bwd(res, g):
+    lhs, rhs, sizes = res
+    _, vjp = jax.vjp(lambda a, b: lax.ragged_dot(a, b, sizes), lhs, rhs)
+    d_lhs, d_rhs = vjp(_rows_in_groups(g, sizes))
+    return _rows_in_groups(d_lhs, sizes), d_rhs, None
+
+
+_gmm.defvjp(lambda lhs, rhs, sizes: (_gmm(lhs, rhs, sizes),
+                                     (lhs, rhs, sizes)), _gmm_bwd)
+
+
+def moe_apply(p: Params, x: jnp.ndarray, cfg: ModelConfig
+              ) -> Tuple[jnp.ndarray, dict]:
+    """x: (B, S, D) -> (y, aux). Routes every token over all experts and
+    computes the part of the result that the experts held here give, for
+    every token choice that lands on them (none is dropped): the choices
+    sorted by held expert, gate/up/down as grouped matmuls over those
+    groups, unsorted and weighted; the shared experts added once."""
     m: MoECfg = cfg.moe
     B, S, D = x.shape
-    E, k = m.n_experts, m.top_k
-    T = B * S
-    g = _largest_divisor(T, m.group_size)
-    n = T // g
-    xg = x.reshape(n, g, D)
-
-    logits = (xg.astype(jnp.float32) @ p["router"])          # (n, g, E)
-    probs = jax.nn.softmax(logits, axis=-1)
-    gate_w, gate_idx = lax.top_k(probs, k)                   # (n, g, k)
-    gate_w = gate_w / jnp.maximum(gate_w.sum(-1, keepdims=True), 1e-9)
-
-    # capacity per expert per group; serving paths (no_drop) size the
-    # buffers so no token can ever overflow
-    C = g * k if no_drop else int(math.ceil(g * k / E * m.capacity_factor))
-    # position of each (token, choice) within its expert, in token order
-    oh = jax.nn.one_hot(gate_idx, E, dtype=jnp.float32)      # (n, g, k, E)
-    tok_e = oh.sum(2)                                        # (n, g, E)
-    pos_base = jnp.cumsum(tok_e, axis=1) - tok_e             # tokens before t
-    within = jnp.cumsum(oh, axis=2) - oh                     # earlier choices
-    pos = (pos_base[:, :, None, :] + within) * oh            # (n, g, k, E)
-    pos = pos.sum(-1)                                        # (n, g, k)
-    keep = (pos < C).astype(jnp.float32)
-    pos = pos.astype(jnp.int32)
-
-    # dispatch/combine tensors (n, g, E, C)
-    pos_oh = jax.nn.one_hot(pos, C, dtype=jnp.float32)       # (n, g, k, C)
-    disp = jnp.einsum("ngke,ngkc->ngec", oh, pos_oh * keep[..., None])
-    comb = jnp.einsum("ngke,ngkc->ngec", oh * gate_w[..., None],
-                      pos_oh * keep[..., None])
-
-    xe = jnp.einsum("ngec,ngd->necd", disp.astype(x.dtype), xg)  # (n,E,C,D)
-    # NOTE (measured, see EXPERIMENTS.md §Perf): forcing the dispatch
-    # output onto an expert-parallel layout here (shard xe over 'expert')
-    # REGRESSED every MoE cell — the token-group dim loses its batch
-    # sharding and the full dispatch buffer replicates. XLA's choice
-    # (all-gather the 2D-sharded expert bank per layer) is cheaper at
-    # these expert sizes; kept as the baseline.
-    h = _act(jnp.einsum("necd,edf->necf", xe, p["w_gate"].astype(x.dtype)),
-             cfg.act)
-    h = h * jnp.einsum("necd,edf->necf", xe, p["w_up"].astype(x.dtype))
-    ye = jnp.einsum("necf,efd->necd", h, p["w_down"].astype(x.dtype))
-    y = jnp.einsum("ngec,necd->ngd", comb.astype(x.dtype), ye)
-
-    # load-balancing aux loss (Switch): E * mean_e(f_e * p_e)
-    f_e = tok_e.mean(axis=(0, 1)) / k                        # fraction routed
-    p_e = probs.mean(axis=(0, 1))
-    aux = E * jnp.sum(f_e * p_e) * m.router_aux_weight
-
-    y = y.reshape(B, S, D)
+    T, k, H = B * S, m.top_k, m.held
+    xt = x.reshape(T, D)
+    with jax.named_scope("moe_router"):
+        probs, w, idx = moe_route(p, xt, m)
+        loss = moe_balance_loss(probs, idx, m, B)
+    with jax.named_scope("moe_permute"):
+        e = idx.reshape(T * k)
+        held = (e >= 0) & (e < H)
+        e = jnp.where(held, e, H)            # not held here: sorted last
+        order = jnp.argsort(e, stable=True)
+        inverse = jnp.argsort(order)
+        sizes = jnp.bincount(e, length=H + 1)[:H].astype(jnp.int32)
+        rows = _permute(jnp.repeat(xt, k, axis=0), order, inverse)
+    with jax.named_scope("moe_experts"):
+        def gmm(lhs, w_):
+            return _gmm(lhs, w_.astype(lhs.dtype), sizes)
+        h = _act(gmm(rows, p["w_gate"]), cfg.act) * gmm(rows, p["w_up"])
+        out = gmm(h, p["w_down"])
+    with jax.named_scope("moe_combine"):
+        # the float32 gate weights stay float32 (a sum over k, and its
+        # gradient a sum over D): the router's gradient comes through it
+        out = _permute(out, inverse, order)
+        wk = jnp.where(held, w.reshape(T * k), 0.0).reshape(T, k)
+        y = jnp.einsum("tk,tkd->td", wk, out.reshape(T, k, D),
+                       precision=lax.Precision.HIGHEST,
+                       preferred_element_type=jnp.float32)
+        y = y.astype(x.dtype).reshape(B, S, D)
     if m.n_shared_experts:
-        y = y + mlp_apply(p["shared"], x, cfg)
+        with jax.named_scope("moe_shared"):
+            y = y + mlp_apply(p["shared"], x, cfg)
+    aux = {"loss": loss, "moe_routed": held.sum().astype(jnp.float32),
+           "moe_load_max": sizes.max().astype(jnp.float32)}
     return y, aux
 
 
 def moe_apply_naive(p: Params, x: jnp.ndarray, cfg: ModelConfig
                     ) -> jnp.ndarray:
-    """Oracle: per-token dense evaluation of all experts (no capacity drops).
-
-    Used only in tests on tiny shapes to validate the dispatch path.
-    """
+    """Oracle: every held expert evaluated densely on every token, weighted
+    by its gate where chosen (zero elsewhere), plus the shared experts.
+    Used only in tests on tiny shapes."""
     m: MoECfg = cfg.moe
     B, S, D = x.shape
-    logits = x.astype(jnp.float32) @ p["router"]
-    probs = jax.nn.softmax(logits, axis=-1)
-    gate_w, gate_idx = lax.top_k(probs, m.top_k)
-    gate_w = gate_w / jnp.maximum(gate_w.sum(-1, keepdims=True), 1e-9)
+    _, gate_w, gate_idx = moe_route(p, x.reshape(B * S, D), m)
     h = _act(jnp.einsum("bsd,edf->bsef", x, p["w_gate"].astype(x.dtype)),
              cfg.act)
     h = h * jnp.einsum("bsd,edf->bsef", x, p["w_up"].astype(x.dtype))
     ye = jnp.einsum("bsef,efd->bsed", h, p["w_down"].astype(x.dtype))
-    sel = jax.nn.one_hot(gate_idx, m.n_experts, dtype=jnp.float32)
-    w = jnp.einsum("bske,bsk->bse", sel, gate_w).astype(x.dtype)
-    y = jnp.einsum("bse,bsed->bsd", w, ye)
+    sel = jax.nn.one_hot(gate_idx, m.held, dtype=jnp.float32)
+    w = jnp.einsum("tke,tk->te", sel, gate_w).astype(x.dtype)
+    y = jnp.einsum("bse,bsed->bsd", w.reshape(B, S, -1), ye)
     if m.n_shared_experts:
         y = y + mlp_apply(p["shared"], x, cfg)
     return y

@@ -61,9 +61,9 @@ def _mixer(p: Params, x, cfg: ModelConfig, spec: LayerSpec, positions):
 
 
 def block_apply(p: Params, x, cfg: ModelConfig, spec: LayerSpec,
-                positions) -> Tuple[jnp.ndarray, jnp.ndarray]:
-    """Returns (x, aux_loss)."""
-    aux = jnp.zeros((), jnp.float32)
+                positions) -> Tuple[jnp.ndarray, dict]:
+    """Returns (x, aux): aux as ``common.aux_zero`` lays it out."""
+    aux = cm.aux_zero(cfg)
     h = cm.apply_norm(p["pre_norm"], x, cfg.norm_kind, cfg.norm_eps)
     a = _mixer(p, h, cfg, spec, positions)
     if cfg.post_norm:
@@ -170,7 +170,7 @@ def block_decode(p: Params, x, cache, cfg: ModelConfig, spec: LayerSpec,
     if spec.ffn != "none":
         h = cm.apply_norm(p["mlp_norm"], x, cfg.norm_kind, cfg.norm_eps)
         if spec.ffn == "moe":
-            f, _ = cm.moe_apply(p["moe"], h, cfg, no_drop=True)
+            f, _ = cm.moe_apply(p["moe"], h, cfg)
         else:
             f = cm.mlp_apply(p["mlp"], h, cfg)
         if cfg.post_norm:
@@ -182,11 +182,11 @@ def block_decode(p: Params, x, cache, cfg: ModelConfig, spec: LayerSpec,
 
 def block_prefill(p: Params, x, cfg: ModelConfig, spec: LayerSpec,
                   positions, cap: int, long_ctx: bool = False
-                  ) -> Tuple[jnp.ndarray, jnp.ndarray, dict]:
+                  ) -> Tuple[jnp.ndarray, dict, dict]:
     """Forward one block while building its decode cache. Returns
     (x, aux, cache). ``cap`` >= S is the cache capacity."""
     B, S, _ = x.shape
-    aux = jnp.zeros((), jnp.float32)
+    aux = cm.aux_zero(cfg)
     h = cm.apply_norm(p["pre_norm"], x, cfg.norm_kind, cfg.norm_eps)
     if spec.mixer == "attn":
         cache = _attn_cache_init(cfg, B, cap, long_ctx)
@@ -216,12 +216,7 @@ def block_prefill(p: Params, x, cfg: ModelConfig, spec: LayerSpec,
     if spec.ffn != "none":
         h = cm.apply_norm(p["mlp_norm"], x, cfg.norm_kind, cfg.norm_eps)
         if spec.ffn == "moe":
-            # capacity-bounded routing at prefill scale: no_drop capacity
-            # is O(group*k) and blows up the dispatch tensors at 1M-token
-            # prefills (measured: deepseek prefill_32k 474 GB/dev).
-            # Decode (tiny T) stays exact via no_drop.
-            f, aux = cm.moe_apply(p["moe"], h, cfg,
-                                  no_drop=x.shape[0] * x.shape[1] <= 4096)
+            f, aux = cm.moe_apply(p["moe"], h, cfg)
         else:
             f = cm.mlp_apply(p["mlp"], h, cfg)
         if cfg.post_norm:
@@ -254,21 +249,20 @@ def init(key, cfg: ModelConfig) -> Params:
 
 def _stack_forward(params, x, cfg: ModelConfig, positions):
     """Run prologue + scanned pattern. Returns (x, aux)."""
-    aux0 = jnp.zeros((), jnp.float32)
-    aux = aux0
+    aux = cm.aux_zero(cfg)
     for i, spec in enumerate(cfg.prologue):
         blk = (jax.checkpoint(functools.partial(block_apply, cfg=cfg,
                                                 spec=spec))
                if cfg.remat else
                functools.partial(block_apply, cfg=cfg, spec=spec))
         x, a = blk(params["prologue"][i], x, positions=positions)
-        aux = aux + a
+        aux = cm.aux_add(aux, a)
 
     def body(carry, period_params):
         x, aux = carry
         for pos, spec in enumerate(cfg.pattern):
             x, a = block_apply(period_params[pos], x, cfg, spec, positions)
-            aux = aux + a
+            aux = cm.aux_add(aux, a)
         return (x, aux), None
 
     if cfg.n_periods:
@@ -298,7 +292,7 @@ def forward(params: Params, tokens: jnp.ndarray, cfg: ModelConfig,
             positions: Optional[jnp.ndarray] = None,
             inputs_embeds: Optional[jnp.ndarray] = None
             ) -> Tuple[jnp.ndarray, jnp.ndarray]:
-    """tokens: (B, S) int32 -> (logits (B,S,V) f32, aux loss)."""
+    """tokens: (B, S) int32 -> (logits (B,S,V) f32, auxiliary loss)."""
     if positions is None:
         S = tokens.shape[1] if inputs_embeds is None else inputs_embeds.shape[1]
         positions = jnp.arange(S)
@@ -309,11 +303,11 @@ def forward(params: Params, tokens: jnp.ndarray, cfg: ModelConfig,
     x = cm.apply_norm(params["final_norm"], x, cfg.norm_kind, cfg.norm_eps)
     logits = cm.logits_apply(params["embed"], x, cfg)
     logits = pt.shard(logits, "batch", "seq", "vocab")
-    return logits, aux
+    return logits, aux["loss"]
 
 
 def final_hidden(params: Params, tokens: jnp.ndarray, cfg: ModelConfig):
-    """Backbone up to (and incl.) the final norm. Returns (x, aux)."""
+    """Backbone up to (and incl.) the final norm. Returns (x, aux dict)."""
     positions = jnp.arange(tokens.shape[1])
     x = cm.embed_apply(params["embed"], tokens, cfg)
     x = pt.shard(x, "batch", "seq", "embed")
@@ -331,7 +325,7 @@ def loss_fn(params: Params, batch: dict, cfg: ModelConfig) -> jnp.ndarray:
     x, aux = final_hidden(params, batch["tokens"], cfg)
     loss = cm.lm_head_loss(head_matrix(params, cfg), x, batch["labels"],
                            cfg, batch.get("mask"))
-    return loss + aux
+    return loss + aux["loss"]
 
 
 def prefill(params: Params, tokens: jnp.ndarray, cfg: ModelConfig,

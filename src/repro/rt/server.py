@@ -96,7 +96,7 @@ class RTServer:
         def _server_phase(srv, srv_opt, step, smashed_flat, flat):
             def srv_loss(s, sm):
                 loss, aux = split.server_loss(s, sm, flat)
-                return loss + aux, loss
+                return loss + aux["loss"], loss
 
             (_, loss), (g_srv, g_smashed) = jax.value_and_grad(
                 srv_loss, argnums=(0, 1), has_aux=True)(srv, smashed_flat)

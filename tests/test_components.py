@@ -1,6 +1,8 @@
 """Component-level units: MoE dispatch vs per-token oracle, SSD impls,
 MLA absorption, chunked CE, norms, optimizers, data pipeline,
 partitioning rules, HLO parser."""
+import dataclasses
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -19,22 +21,25 @@ KEY = jax.random.PRNGKey(0)
 
 # -- MoE ---------------------------------------------------------------------
 
-def _moe_cfg(E=8, k=2, g=16, cf=8.0, shared=0):
+def _moe_cfg(E=8, k=2, shared=0):
     return ModelConfig(
         name="t", family="moe", d_model=32, n_layers=2, n_heads=2,
         n_kv_heads=2, d_ff=64, vocab_size=64, dtype="float32",
-        moe=MoECfg(n_experts=E, top_k=k, d_ff_expert=32, group_size=g,
-                   capacity_factor=cf, n_shared_experts=shared))
+        moe=MoECfg(n_experts=E, top_k=k, d_ff_expert=32,
+                   n_shared_experts=shared))
 
 
 def test_moe_dispatch_matches_naive_when_capacity_ample():
-    cfg = _moe_cfg(cf=8.0)   # capacity >> needed: no drops
+    """The dropless grouped path against the dense oracle (no capacity:
+    every token choice is computed)."""
+    cfg = _moe_cfg()
     p = cm.moe_init(KEY, cfg)
     x = jax.random.normal(jax.random.fold_in(KEY, 1), (2, 16, 32))
     y, aux = cm.moe_apply(p, x, cfg)
     y_ref = cm.moe_apply_naive(p, x, cfg)
     assert jnp.abs(y - y_ref).max() < 1e-4
-    assert float(aux) > 0
+    assert float(aux["loss"]) > 0
+    assert float(aux["moe_routed"]) == 2 * 16 * 2
 
 
 def test_moe_shared_experts_added():
@@ -46,15 +51,111 @@ def test_moe_shared_experts_added():
     assert jnp.abs(y - y_ref).max() < 1e-4
 
 
-def test_moe_capacity_drops_tokens():
-    cfg = _moe_cfg(cf=0.25)  # tight capacity: overflow dropped (GShard)
+@pytest.mark.parametrize("arch", [a for a in registry.list_archs()
+                                  if registry.get(a).moe is not None])
+def test_moe_dropless_matches_naive_for_every_moe_config(arch):
+    cfg = registry.reduce_for_smoke(registry.get(arch)).replace(
+        dtype="float32")
     p = cm.moe_init(KEY, cfg)
-    x = jax.random.normal(KEY, (2, 16, 32))
-    y, _ = cm.moe_apply(p, x, cfg)
-    y_ref = cm.moe_apply_naive(p, x, cfg)
-    # some tokens zeroed vs oracle, none exploded
-    assert bool(jnp.isfinite(y).all())
-    assert float(jnp.abs(y - y_ref).max()) > 1e-3
+    x = jax.random.normal(jax.random.fold_in(KEY, 2), (2, 16, cfg.d_model))
+    y, aux = cm.moe_apply(p, x, cfg)
+    assert jnp.abs(y - cm.moe_apply_naive(p, x, cfg)).max() < 1e-4
+    assert 0 < float(aux["moe_routed"]) <= 2 * 16 * cfg.moe.top_k
+
+
+def test_moe_gradients_match_naive():
+    """The grouped path's backward (its masked grouped matmuls, the
+    scatter-free permutations) against the dense oracle's."""
+    cfg = _moe_cfg(shared=1)
+    p = cm.moe_init(KEY, cfg)
+    x = jax.random.normal(jax.random.fold_in(KEY, 5), (2, 16, 32))
+    g = jax.random.normal(jax.random.fold_in(KEY, 6), (2, 16, 32))
+
+    def grouped(p, x):
+        return jnp.sum(cm.moe_apply(p, x, cfg)[0] * g)
+
+    def naive(p, x):
+        return jnp.sum(cm.moe_apply_naive(p, x, cfg) * g)
+
+    got = jax.grad(grouped, argnums=(0, 1))(p, x)
+    want = jax.grad(naive, argnums=(0, 1))(p, x)
+    for a, b in zip(jax.tree.leaves(got), jax.tree.leaves(want)):
+        assert jnp.abs(a - b).max() <= 1e-4 * (1 + jnp.abs(b).max())
+
+
+def test_moe_skewed_router_drops_nothing():
+    """One expert is every token's first choice: a capacity dispatch
+    would drop most of them; the grouped path computes them all."""
+    cfg = _moe_cfg()
+    p = cm.moe_init(KEY, cfg)
+    p["router"] = p["router"].at[:, 3].set(0.0)
+    x = jax.random.normal(jax.random.fold_in(KEY, 3), (2, 16, 32))
+    x = x.at[..., 0].set(8.0)
+    p["router"] = p["router"].at[0, 3].set(4.0)
+    y, aux = cm.moe_apply(p, x, cfg)
+    assert float(aux["moe_load_max"]) == 2 * 16
+    assert jnp.abs(y - cm.moe_apply_naive(p, x, cfg)).max() < 1e-4
+
+
+def test_moe_shares_sum_to_the_uncut_layer():
+    """Four chips' shares of 8 experts (2 held each, the router over all
+    8): their parts of the result summed, with the shared expert counted
+    once, equal the layer with every expert held. A chip holds the first
+    experts of its router's columns: share s rolls them to the front."""
+    cfg = _moe_cfg(shared=1)
+    p = cm.moe_init(KEY, cfg)
+    x = jax.random.normal(jax.random.fold_in(KEY, 4), (2, 16, 32))
+    full, _ = cm.moe_apply(p, x, cfg)
+    shared = cm.mlp_apply(p["shared"], x, cfg)
+    parts, routed = [], 0.0
+    for s in range(4):
+        held = slice(2 * s, 2 * s + 2)
+        ps = dict(p, router=jnp.roll(p["router"], -2 * s, axis=1),
+                  **{k: p[k][held] for k in ("w_gate", "w_up", "w_down")})
+        cs = cfg.replace(moe=dataclasses.replace(cfg.moe, n_held=2))
+        y, aux = cm.moe_apply(ps, x, cs)
+        assert jnp.abs(y - cm.moe_apply_naive(ps, x, cs)).max() < 1e-4
+        parts.append(y - shared)
+        routed += float(aux["moe_routed"])
+    assert jnp.abs(sum(parts) + shared - full).max() < 1e-4
+    assert routed == 2 * 16 * 2
+
+
+def test_rope_without_scaling_is_unchanged():
+    """No rope scaling gives the bits of the plain half-rotation rope."""
+    x = jax.random.normal(KEY, (2, 12, 3, 16)).astype(jnp.bfloat16)
+    pos = jnp.arange(12)
+    freqs = 1.0 / (1e6 ** (jnp.arange(0, 16, 2, dtype=jnp.float32) / 16))
+    ang = pos[:, None].astype(jnp.float32) * freqs
+    cos, sin = jnp.cos(ang)[:, None, :], jnp.sin(ang)[:, None, :]
+    x1, x2 = jnp.split(x.astype(jnp.float32), 2, axis=-1)
+    want = jnp.concatenate([x1 * cos - x2 * sin, x2 * cos + x1 * sin],
+                           -1).astype(x.dtype)
+    assert bool((cm.apply_rope(x, pos, 1e6) == want).all())
+
+
+def test_yarn_frequencies_and_softmax_gain():
+    """DeepSeek-V2-Lite's YaRN: rope dim 64, base 1e4, 4096 original
+    positions, factor 40, betas 32 and 1, mscale = mscale_all_dim = 0.707."""
+    cfg = registry.get("deepseek-v2-lite-16b")
+    y = cfg.rope_scaling
+    d = 64
+
+    def corr(r):
+        return d * np.log(4096 / (2 * np.pi * r)) / (2 * np.log(1e4))
+
+    low, high = max(int(np.floor(corr(32))), 0), min(int(np.ceil(corr(1))),
+                                                      d - 1)
+    assert (low, high) == (10, 23)
+    i = np.arange(32)
+    ramp = np.clip((i - low) / (high - low), 0, 1)
+    extra = 1e4 ** (-2 * i / d)
+    want = extra * (1 - ramp) + extra / 40 * ramp
+    got = np.asarray(cm.rope_freqs(d, 1e4, y))
+    assert np.allclose(got, want, rtol=1e-6)
+    m = 0.1 * 0.707 * np.log(40) + 1
+    assert cm.mla_softmax_gain(cfg) == pytest.approx(m * m)
+    assert cm.mla_softmax_gain(cfg) == pytest.approx(1.5897, abs=1e-4)
 
 
 def test_moe_grad_flows_to_router():
@@ -64,7 +165,7 @@ def test_moe_grad_flows_to_router():
 
     def loss(p):
         y, aux = cm.moe_apply(p, x, cfg)
-        return (y ** 2).mean() + aux
+        return (y ** 2).mean() + aux["loss"]
 
     g = jax.grad(loss)(p)
     assert float(jnp.abs(g["router"]).max()) > 0

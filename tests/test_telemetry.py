@@ -350,3 +350,38 @@ def test_spectrum_table_counters(custom):
     else:
         assert counts["spectrum_table_calls"] == counts["spectrum_calls"]
         assert counts["spectrum_tables"] == 1
+
+
+def test_moe_scopes_and_counters():
+    """The MoE layer's device ops carry its scopes (inside the split's
+    ``server_side``) in the compiled program's op paths, and the looped round folds its counters into the
+    round's ``counts`` at the one sync it already makes."""
+    import re
+
+    import jax
+    from repro import telemetry
+    from repro.configs import registry
+    from repro.configs.base import CPSLConfig
+    from repro.core.cpsl import CPSL
+    from repro.core.splitting import make_split_model
+    cfg = registry.reduce_for_smoke(registry.get("deepseek-v2-lite-16b-ep8"))
+    cp = CPSL(make_split_model(cfg, 1),
+              CPSLConfig(cut_layer=1, n_clusters=2, cluster_size=2,
+                         batch_per_device=2))
+    state = cp.init_state(jax.random.PRNGKey(0))
+    b = registry.concrete_batch(jax.random.PRNGKey(1), cfg, batch=4, seq=16)
+    batch = jax.tree.map(lambda t: t.reshape((2, 2) + t.shape[1:]), b)
+    hlo = jax.jit(cp.fused_step_impl).lower(state, batch).compile().as_text()
+    paths = set(re.findall(r'op_name="([^"]*)"', hlo))
+    for scope in ("mla", "moe_router", "moe_permute", "moe_experts",
+                  "moe_combine", "moe_shared"):
+        assert any("server_side" in p and f"/{scope}/" in p
+                   for p in paths), scope
+    assert any("device_side" in p and "(mla)/" in p for p in paths)
+    telemetry.begin_round(0)
+    state, _ = cp.run_round(state, lambda m, l: batch)
+    _, counts = telemetry.fold()
+    assert counts["syncs"] == 1
+    # 2 clusters x 2 MoE layers x 16 tokens x 2 sequences x 2 devices x top-2
+    assert 0 < counts["moe_routed"] <= 2 * 2 * 16 * 4 * 2
+    assert 0 < counts["moe_load_max"] <= 16 * 4 * 2
