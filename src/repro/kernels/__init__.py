@@ -1,5 +1,7 @@
 """Pallas TPU kernels (flash attention, Mamba-2 SSD), each with a jnp
-oracle (``ref.py``) and a jit-ready wrapper (``ops.py``)."""
+oracle (``ref.py``) and a jit-ready wrapper (``ops.py``); and MLA's
+causal core (``mla_attention``), the installed splash-attention kernels
+on the TPU beside a blocked jnp path (``blocked.py``)."""
 import jax
 
 

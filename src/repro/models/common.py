@@ -18,6 +18,7 @@ from jax import lax
 
 from repro.configs.base import ModelConfig, MoECfg, MLACfg, YaRNCfg
 from repro.core import partitioning as pt
+from repro.kernels.mla_attention import ops as mla_ops
 
 Params = dict
 
@@ -553,15 +554,19 @@ def _mla(p, x, cfg, causal, positions, latent, kv_valid_len, absorbed):
             [k_nope, jnp.broadcast_to(k_rope[:, :, None, :], (B, Skv, H, dr))],
             axis=-1)
         qq = jnp.concatenate([q_nope, q_rope], axis=-1)
-        if gain != 1.0:
-            qq = qq * gain
-        # full multi-head (G=H, R=1); pad v to qk width for the shared core
-        o = grouped_attention(qq.reshape(B, S, H, 1, dn + dr), k,
-                              jnp.pad(v, ((0, 0), (0, 0), (0, 0),
-                                          (0, dn + dr - dv))),
-                              cfg, causal=causal, q_offset=q_offset,
-                              kv_valid_len=kv_valid_len)
-        o = o.reshape(B, S, H, dn + dr)[..., :dv]
+        # training's full causal self-attention: MLA's own flash core, q·k
+        # over dn + dr, p·v over dv; decode and cache paths: the reference
+        if (causal and latent is None and kv_valid_len is None and S > 1
+                and cfg.attn_impl != "naive"):
+            o = mla_ops.mla_attention(
+                qq, k, v, scale=gain / math.sqrt(dn + dr),
+                bq=_largest_divisor(S, cfg.q_chunk),
+                bkv=_largest_divisor(S, cfg.kv_chunk))
+        else:
+            if gain != 1.0:
+                qq = qq * gain
+            o = naive_attention(qq[:, :, :, None], k, v, causal=causal,
+                                q_offset=q_offset, kv_valid_len=kv_valid_len)
     return dense(p["wo"], o.reshape(B, S, H * dv))
 
 

@@ -18,7 +18,9 @@ from repro.configs import registry
 from repro.configs.base import CPSLConfig
 from repro.core.cpsl import CPSL
 from repro.core.splitting import make_split_model
+from repro import kernels
 from repro.kernels.flash_attention.kernel import flash_attention_flat
+from repro.kernels.mla_attention import ops as mla_ops
 from repro.kernels.ssd.kernel import ssd_flat
 
 V5E_HBM_BYTES = 16 * 1024 ** 3
@@ -88,6 +90,28 @@ def test_ssd_compiles_for_v5e(one_chip):
         _spec(one_chip, (BH, S, N))).compile()
     _fits(compiled)
     assert "tpu_custom_call" in compiled.as_text()
+
+
+def test_mla_core_compiles_for_v5e(one_chip, monkeypatch):
+    """MLA's causal core with its backward at DeepSeek-V2-Lite's widths
+    (16 heads, q-k 192, v 128) over the cell's server batch, 4 x 2048:
+    the splash kernels (``kernels.interpret`` steered off, as on a TPU)."""
+    monkeypatch.setattr(kernels, "interpret", lambda: False)
+    m = registry.get("deepseek-v2-lite-16b-ep8").mla
+    B, S, H = 4, 2048, 16
+    dqk, dv = m.qk_nope_head_dim + m.qk_rope_head_dim, m.v_head_dim
+
+    def step(q, k, v, g):
+        o, vjp = jax.vjp(
+            lambda q, k, v: mla_ops.splash_flash(q, k, v, dqk ** -0.5),
+            q, k, v)
+        return o, vjp(g)
+
+    qk = _spec(one_chip, (B, S, H, dqk), jnp.bfloat16)
+    vv = _spec(one_chip, (B, S, H, dv), jnp.bfloat16)
+    compiled = jax.jit(step).lower(qk, qk, vv, vv).compile()
+    _fits(compiled)
+    assert compiled.as_text().count("tpu_custom_call") >= 3
 
 
 def test_fused_lenet_round_compiles_for_v5e(one_chip):
