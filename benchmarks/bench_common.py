@@ -5,7 +5,6 @@ from __future__ import annotations
 
 import json
 import os
-import time
 from dataclasses import dataclass
 from typing import Dict, List, Optional
 
@@ -68,9 +67,8 @@ def paper_network(seed=0, homogeneous=True, bw_mhz=30):
 # -- schemes -----------------------------------------------------------------
 
 # The whole-curve jit caches on the CPSL instance (jit static self), so
-# sweep variants that share a (padded) shape MUST share the instance to
-# reuse one compiled executable — this cache is what turns the fig6
-# N_m sweep's three compiles into one.
+# runs of one configuration share the instance to reuse one compiled
+# executable (fig5 and fig6 both run vanilla SL).
 _CPSL_CACHE: Dict[CPSLConfig, CPSL] = {}
 
 
@@ -83,12 +81,12 @@ def cpsl_for(ccfg: CPSLConfig) -> CPSL:
 
 
 def fleet_ccfg(cluster_size, n_clusters, local_epochs=1, lr=0.05,
-               cut=3, pad_to=None) -> CPSLConfig:
+               cut=3) -> CPSLConfig:
     """The benchmark training config on the fleet lowering: im2col convs
     + scanned cluster/round axes (compile cost independent of the curve
-    length), padded to ``pad_to`` when given."""
-    M, K = pad_to if pad_to else (n_clusters, cluster_size)
-    return CPSLConfig(cut_layer=cut, n_clusters=M, cluster_size=K,
+    length)."""
+    return CPSLConfig(cut_layer=cut, n_clusters=n_clusters,
+                      cluster_size=cluster_size,
                       local_epochs=local_epochs, lr_device=lr,
                       lr_server=lr, conv_impl="im2col", scan_rounds=True,
                       fused_round_unroll=1)
@@ -96,45 +94,25 @@ def fleet_ccfg(cluster_size, n_clusters, local_epochs=1, lr=0.05,
 
 def run_cpsl(data: BenchData, rounds: int, cluster_size=5, n_clusters=6,
              local_epochs=1, lr=0.05, cut=3, seed=0, eval_every=1,
-             pad_to=None, sl_latency=False,
-             measure_steady=False) -> Dict:
+             sl_latency=False) -> Dict:
     """CPSL (paper Alg. 1) as ONE fused training-curve dispatch
     (``CPSL.run_training_fused``): device-resident data + eval split,
     in-jit eval every ``eval_every`` rounds, wireless latency priced
     host-side with the equal spectrum split (unchanged from the looped
-    version).
-
-    ``pad_to=(M, K)`` pads the cluster layout (masked) to a shared
-    shape so every sweep variant reuses one compiled executable instead
-    of recompiling per variant. The output dict reports ``first_call_s``
-    (compile + run) and, with ``measure_steady``, a second dispatch's
-    ``steady_s`` and the derived ``compile_s`` separately."""
+    version)."""
     assert rounds % eval_every == 0, (rounds, eval_every)
-    ccfg = fleet_ccfg(cluster_size, n_clusters, local_epochs, lr, cut,
-                      pad_to)
+    ccfg = fleet_ccfg(cluster_size, n_clusters, local_epochs, lr, cut)
     cp = cpsl_for(ccfg)
     layout = [list(range(m * cluster_size, (m + 1) * cluster_size))
               for m in range(n_clusters)]
     plan = fleet_plan([data.device_idx], 16, [layout], [seed], rounds,
-                      local_epochs, pad_to=pad_to)
+                      local_epochs)
     dsd = DeviceResidentDataset(data.xtr, data.ytr, data.device_idx, 16,
                                 eval_images=data.xte, eval_labels=data.yte)
-
-    def one_run():
-        state = cp.init_state(jax.random.PRNGKey(seed))
-        state, metrics = cp.run_training_fused(
-            state, dsd.data, plan.idx[0], plan.weights[0],
-            eval_data=dsd.eval_data, eval_every=eval_every,
-            cluster_mask=None if plan.cluster_mask is None
-            else plan.cluster_mask[0],
-            client_mask=None if plan.client_mask is None
-            else plan.client_mask[0])
-        jax.block_until_ready(metrics["loss"])
-        return metrics
-
-    t0 = time.perf_counter()
-    metrics = one_run()
-    first_call = time.perf_counter() - t0
+    state = cp.init_state(jax.random.PRNGKey(seed))
+    _, metrics = cp.run_training_fused(
+        state, dsd.data, plan.idx[0], plan.weights[0],
+        eval_data=dsd.eval_data, eval_every=eval_every)
 
     times = equal_split_latency(rounds, cluster_size, n_clusters, seed,
                                 local_epochs, sl_latency)
@@ -143,13 +121,7 @@ def run_cpsl(data: BenchData, rounds: int, cluster_size=5, n_clusters=6,
     hist = {"round": list(ev),
             "acc": [float(a) for a in np.asarray(metrics["eval"]["acc"])],
             "loss": [float(loss[r]) for r in ev],
-            "time": [times[r] for r in ev],
-            "first_call_s": first_call}
-    if measure_steady:
-        t0 = time.perf_counter()
-        one_run()
-        hist["steady_s"] = time.perf_counter() - t0
-        hist["compile_s"] = max(first_call - hist["steady_s"], 0.0)
+            "time": [times[r] for r in ev]}
     return hist
 
 

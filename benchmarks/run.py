@@ -21,10 +21,8 @@ import traceback
 
 from repro import compile_cache
 
-from benchmarks import (bench_dynamics, bench_fleet, bench_planner,
-                        bench_round, bench_rt, bench_scale, bench_simfleet,
-                        fig5_training, fig6_cluster_size, fig7_cut_layer,
-                        fig8_resource, roofline, table2_latency)
+from benchmarks import (bench_rt, fig5_training, fig6_cluster_size,
+                        fig7_cut_layer, fig8_resource, table2_latency)
 
 BENCHES = {
     "table2_latency": table2_latency.main,
@@ -33,14 +31,7 @@ BENCHES = {
     "fig8b_smoke": fig8_resource.smoke,
     "fig5_training": fig5_training.main,
     "fig6_cluster_size": fig6_cluster_size.main,
-    "roofline": roofline.main,
-    "bench_dynamics": bench_dynamics.main,
-    "bench_planner": bench_planner.main,
-    "bench_round": bench_round.main,
-    "bench_fleet": bench_fleet.main,
-    "bench_simfleet": bench_simfleet.main,
     "bench_rt": bench_rt.main,
-    "bench_scale": bench_scale.main,
 }
 
 

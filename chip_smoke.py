@@ -16,9 +16,9 @@ device's ``peak_bytes_in_use`` so far:
   lm_split       qwen2-0.5b at its published widths through the same
                  launcher (fixed cut v=2, M=2, K=2, B=4, seq 512), plus
                  the compiled flash-attention kernel at its shapes;
-  episode_fleet  ``SimFleetRunner._sim`` under float64 at the CI-smoke
-                 size of ``benchmarks/bench_simfleet`` (2 seeds x 2
-                 policies), decisions against ``run_reference``;
+  episode_fleet  ``SimFleetRunner._sim`` under float64 at a smoke size
+                 (2 seeds x 2 policies), decisions against
+                 ``run_reference``;
   rt_loopback    the ``examples/rt_loopback.py`` deployment with the
                  server on the chip and 4 CPU workers.
 
@@ -291,7 +291,7 @@ def episode_fleet(seeds=2, rounds=8):
     from repro.sim.dynamics import DynamicsCfg
     from repro.sim.fleet import SimFleetRunner, fleet_trace_records
 
-    # benchmarks/bench_simfleet.py's benchmark arm (N=C=30, K=5, cut 3)
+    # the paper's N=C=30, K=5 at cut 3, Gauss-Markov fading with churn
     prof = lenet_profile()
     ncfg = NetworkCfg(n_devices=30, n_subcarriers=30)
     dcfg = DynamicsCfg(rho_snr=0.9, rho_f=0.95, seed=SEED,

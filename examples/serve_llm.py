@@ -1,4 +1,4 @@
-"""Batched LLM serving with the engine the decode-shape dry-runs lower.
+"""Batched LLM serving with the decode engine (``repro.serving``).
 
 Prefill + greedy decode on a reduced gemma2-2b (alternating local/global
 attention, softcaps) and a reduced mamba2 (SSM state cache — O(1) decode).

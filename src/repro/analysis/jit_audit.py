@@ -29,9 +29,9 @@ Audited targets (the acceptance set):
   * ``SimFleetRunner._sim``        — the two-timescale Monte-Carlo
     simulator (traced under ``jax.enable_x64(True)``, its contract dtype).
 
-Also exported: the shared recompile-guard helpers ``cache_size`` and
-``CompileCounter`` (used by ``benchmarks/bench_fleet.py`` instead of
-ad-hoc ``_cache_size`` asserts).
+Also exported: the recompile-guard helpers ``cache_size`` and
+``CompileCounter`` (``tests/test_fleet.py`` guards the padded fleet
+layout with them).
 """
 
 from __future__ import annotations
@@ -49,7 +49,7 @@ TARGET_NAMES = ("round_fused", "training_fused", "fleet", "fleet_sim")
 _CALLBACK_PRIMS = {"infeed", "outfeed"}
 
 
-# -- shared helpers (also the benchmarks' recompile guard) -----------------
+# -- recompile guard --------------------------------------------------------
 
 def cache_size(jitfn) -> int:
     """Number of compiled entries in a ``jax.jit`` function's cache."""

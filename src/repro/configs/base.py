@@ -2,8 +2,8 @@
 
 A ModelConfig fully describes one architecture in the zoo. Layer stacks are
 expressed as an optional unrolled ``prologue`` followed by a periodic
-``pattern`` that is scanned ``n_periods`` times (compact HLO => fast SPMD
-compiles at 512 devices). Heterogeneous stacks (gemma2 local/global, jamba
+``pattern`` that is scanned ``n_periods`` times (compact HLO, fast
+compiles). Heterogeneous stacks (gemma2 local/global, jamba
 1:7 mamba:attn with alternating MoE) are one period of the repeating unit.
 """
 from __future__ import annotations
@@ -173,15 +173,13 @@ class CPSLConfig:
     fused_round_unroll: int = 0      # scan unroll for the fused round; 0 = full
                                      # unroll (XLA:CPU lowers conv grads inside
                                      # while-loop bodies to its naive emitter,
-                                     # ~40x slower — measured in bench_round)
+                                     # ~40x slower, measured on XLA:CPU)
     unroll_clients: bool = False     # trace-time unroll of the K-client device
                                      # pass instead of jax.vmap: vmap over
                                      # per-client weights lowers conv grads to
                                      # grouped convolutions (~10x slower on
                                      # XLA:CPU); ULP-level lowering differences
                                      # vs the vmapped form (tested)
-    microbatches: int = 1            # grad-accumulation splits of B
-    share_device_params: bool = False  # L==1 fast path (beyond-paper)
     straggler_dropout: float = 0.0   # fraction of clients allowed to miss FedAvg
     compress_uploads: str = "none"   # none | topk | int8 (device-model uploads)
     compress_topk: float = 0.1
@@ -229,34 +227,6 @@ class FleetConfig:
 
     def replace(self, **kw):
         return dataclasses.replace(self, **kw)
-
-
-@dataclass(frozen=True)
-class MeshConfig:
-    data: int = 16
-    model: int = 16
-    pods: int = 1                    # >1 adds leading "pod" axis
-
-    @property
-    def n_devices(self) -> int:
-        return self.data * self.model * self.pods
-
-
-@dataclass(frozen=True)
-class ShapeCfg:
-    """One input-shape cell from the assignment."""
-    name: str                        # train_4k | prefill_32k | decode_32k | long_500k
-    seq_len: int
-    global_batch: int
-    kind: str                        # train | prefill | decode
-
-
-SHAPES = {
-    "train_4k":    ShapeCfg("train_4k", 4096, 256, "train"),
-    "prefill_32k": ShapeCfg("prefill_32k", 32768, 32, "prefill"),
-    "decode_32k":  ShapeCfg("decode_32k", 32768, 128, "decode"),
-    "long_500k":   ShapeCfg("long_500k", 524288, 1, "decode"),
-}
 
 
 @dataclass

@@ -1,5 +1,5 @@
-"""Architecture registry: ``--arch <id>`` lookup, input specs per shape
-cell, and reduced configs for CPU smoke tests.
+"""Architecture registry: ``--arch <id>`` lookup, the shape cells each
+architecture is sized for, and reduced configs for CPU smoke tests.
 
 The 4 shape cells (assignment):
     train_4k:    seq 4096,   global_batch 256  -> CPSL train_step
@@ -19,8 +19,7 @@ import jax.numpy as jnp
 from repro.configs import (chameleon_34b, deepseek_v2_lite_16b, gemma2_2b,
                            jamba_v01_52b, mamba2_2p7b, phi35_moe_42b,
                            qwen2_05b, qwen25_14b, qwen3_32b, whisper_small)
-from repro.configs.base import (LayerSpec, MLACfg, ModelConfig, MoECfg,
-                                SHAPES, SSMCfg, ShapeCfg)
+from repro.configs.base import LayerSpec, MLACfg, ModelConfig, MoECfg, SSMCfg
 
 ARCHS = {
     "whisper-small": whisper_small.config,
@@ -56,36 +55,6 @@ def cells(arch: str):
     if arch in LONG_CTX_ARCHS:
         out.append("long_500k")
     return out
-
-
-# --------------------------------------------------------------------------
-# input specs (ShapeDtypeStruct stand-ins — no allocation)
-# --------------------------------------------------------------------------
-
-def input_specs(cfg: ModelConfig, shape: ShapeCfg) -> Dict:
-    """Abstract input batch for the given shape cell.
-
-    train/prefill: token batch (+ frames for enc-dec).
-    decode: token column; the (large) cache spec is built separately via
-    ``jax.eval_shape`` over the cache initializer (see launch/dryrun.py).
-    """
-    gb, S = shape.global_batch, shape.seq_len
-    i32 = jnp.int32
-    sds = jax.ShapeDtypeStruct
-    if shape.kind == "train":
-        batch = {"tokens": sds((gb, S), i32), "labels": sds((gb, S), i32)}
-        if cfg.encdec:
-            batch["frames"] = sds((gb, cfg.enc_seq, cfg.d_model),
-                                  jnp.dtype(cfg.dtype))
-        return batch
-    if shape.kind == "prefill":
-        batch = {"tokens": sds((gb, S), i32)}
-        if cfg.encdec:
-            batch["frames"] = sds((gb, cfg.enc_seq, cfg.d_model),
-                                  jnp.dtype(cfg.dtype))
-        return batch
-    # decode: one new token at position S-1 given a cache of capacity S
-    return {"tokens": sds((gb,), i32)}
 
 
 # --------------------------------------------------------------------------

@@ -1,6 +1,6 @@
 """whisper-small [audio]: enc-dec, 12 enc + 12 dec layers, d=768, 12H
 (kv=12), d_ff=3072, vocab=51865. Conv/log-mel frontend is a STUB —
-input_specs provides precomputed frame embeddings. [arXiv:2212.04356]
+registry.concrete_batch provides precomputed frame embeddings. [arXiv:2212.04356]
 """
 from repro.configs.base import LayerSpec, ModelConfig
 

@@ -60,7 +60,6 @@ from repro import streams, telemetry
 from repro import optim
 from repro.configs.base import CPSLConfig
 from repro.core import compression as cmp
-from repro.core import partitioning as pt
 from repro.core.splitting import SplitModel
 from repro.models import common as cm
 
@@ -94,7 +93,7 @@ class CPSL:
 
     def init_state(self, key) -> dict:
         k1, k2, k3 = jax.random.split(key, 3)
-        K = 1 if self.ccfg.share_device_params else self.ccfg.cluster_size
+        K = self.ccfg.cluster_size
         dev0 = self.split.init_device(k1)
         dev = jax.tree.map(
             lambda t: jnp.broadcast_to(t[None], (K,) + t.shape), dev0)
@@ -121,7 +120,7 @@ class CPSL:
         ``jax.vmap`` over per-client weights lowers the device conv
         gradients to grouped convolutions, which XLA:CPU executes on its
         naive emitter — ~10x slower than the K plain convolutions this
-        unrolled form emits (measured in benchmarks/bench_round.py).
+        unrolled form emits (measured on XLA:CPU).
         Results match the vmapped lowering to ULP (tested); TPU/GPU are
         indifferent, so ``unroll_clients`` stays off by default."""
         K = jax.tree.leaves(dev)[0].shape[0]
@@ -134,26 +133,15 @@ class CPSL:
 
     def _total_loss(self, dev, srv, batch):
         """batch leaves: (K, B, ...). Returns (scalar, metrics)."""
-        if self.ccfg.share_device_params:
-            flat = _flat(batch)
-            dev0 = jax.tree.map(lambda t: t[0], dev)
-            with jax.named_scope("device_side"):
-                smashed, aux_d = self.split.device_apply(dev0, flat)
+        if self.ccfg.unroll_clients:
+            smashed, aux_d = self._clients_unrolled(dev, batch)
         else:
-            if self.ccfg.unroll_clients:
-                smashed, aux_d = self._clients_unrolled(dev, batch)
-            else:
-                K = jax.tree.leaves(dev)[0].shape[0]
-                ax = pt.spmd_client_axes(K)
-                with pt.exclude_axes(ax), jax.named_scope("device_side"):
-                    smashed, aux_d = jax.vmap(
-                        self.split.device_apply, spmd_axis_name=ax)(dev,
-                                                                    batch)
-            # eq. (5): concatenate client smashed data into the server batch
-            smashed = smashed.reshape((-1,) + smashed.shape[2:])
-            aux_d = cm.aux_over_clients(aux_d)
-            flat = _flat(batch)
-        smashed = pt.shard(smashed, "batch")
+            with jax.named_scope("device_side"):
+                smashed, aux_d = jax.vmap(self.split.device_apply)(dev, batch)
+        # eq. (5): concatenate client smashed data into the server batch
+        smashed = smashed.reshape((-1,) + smashed.shape[2:])
+        aux_d = cm.aux_over_clients(aux_d)
+        flat = _flat(batch)
         with jax.named_scope("server_side"):
             loss, aux_s = self.split.server_loss(srv, smashed, flat)
         aux = cm.aux_add(aux_d, aux_s)
@@ -162,44 +150,15 @@ class CPSL:
     # -- fused step ----------------------------------------------------------
 
     def fused_step_impl(self, state, batch, lr_scale=None):
-        """Unjitted fused step — the dry-run wraps this with explicit
-        in/out shardings; interactive use goes through the jitted method.
-
-        ccfg.microbatches > 1 splits the per-client batch B and
-        accumulates gradients over a rematted scan (activation memory
-        scales 1/m; the straggler/latency model is unaffected — the
-        device still processes B samples per epoch).
+        """Unjitted fused step, the body that the jitted step and the
+        fused round share.
 
         ``lr_scale``: optional traced scalar multiplying both optimizers'
         learning rates (fleet per-replica hyper-parameters as data)."""
         grad_fn = jax.value_and_grad(self._total_loss, argnums=(0, 1),
                                      has_aux=True)
-        m = self.ccfg.microbatches
-        if m > 1:
-            mb = jax.tree.map(
-                lambda t: jnp.moveaxis(
-                    t.reshape((t.shape[0], m, t.shape[1] // m)
-                              + t.shape[2:]), 1, 0), batch)
-
-            def acc(carry, mbatch):
-                g_dev, g_srv, mt_acc = carry
-                (_, mt), (gd, gs) = grad_fn(state["dev"], state["srv"],
-                                            mbatch)
-                g_dev = jax.tree.map(lambda a, b: a + b / m, g_dev, gd)
-                g_srv = jax.tree.map(lambda a, b: a + b / m, g_srv, gs)
-                mt = dict(mt, loss=mt["loss"] / m, aux=mt["aux"] / m)
-                return (g_dev, g_srv, cm.aux_add(mt_acc, mt)), None
-
-            zeros = lambda t: jax.tree.map(  # noqa: E731
-                lambda p: jnp.zeros(p.shape, jnp.float32), t)
-            mt0 = jax.eval_shape(grad_fn, state["dev"], state["srv"],
-                                 jax.tree.map(lambda t: t[0], mb))[0][1]
-            (g_dev, g_srv, metrics), _ = jax.lax.scan(
-                acc, (zeros(state["dev"]), zeros(state["srv"]),
-                      zeros(mt0)), mb)
-        else:
-            (_, metrics), (g_dev, g_srv) = grad_fn(state["dev"],
-                                                   state["srv"], batch)
+        (_, metrics), (g_dev, g_srv) = grad_fn(state["dev"], state["srv"],
+                                               batch)
         with jax.named_scope("update"):
             new_dev, dev_opt = self.dev_opt.step(g_dev, state["dev_opt"],
                                                  state["dev"], state["step"],
@@ -214,25 +173,21 @@ class CPSL:
     @functools.partial(jax.jit, static_argnums=0)
     def _fused_step(self, state, batch):
         # NOTE: no donation here — interactive/test use keeps the input
-        # state alive; the dry-run/launcher jits fused_step_impl with
-        # donate_argnums for production memory behaviour.
+        # state alive; the fused round donates its state.
         return self.fused_step_impl(state, batch)
 
     # -- explicit two-phase protocol step -------------------------------------
 
     def protocol_step_impl(self, state, batch, lr_scale=None):
-        assert not self.ccfg.share_device_params
         split = self.split
 
         # Phase 1 (paper steps 3, eq. 4): device FP -> smashed data
         Kc = jax.tree.leaves(state["dev"])[0].shape[0]
-        ax = pt.spmd_client_axes(Kc)
         if self.ccfg.unroll_clients:
             smashed, _ = self._clients_unrolled(state["dev"], batch)
         else:
-            with pt.exclude_axes(ax), jax.named_scope("device_side"):
-                smashed, _ = jax.vmap(split.device_apply,
-                                      spmd_axis_name=ax)(state["dev"], batch)
+            with jax.named_scope("device_side"):
+                smashed, _ = jax.vmap(split.device_apply)(state["dev"], batch)
         K, B = smashed.shape[:2]
         smashed_flat = smashed.reshape((-1,) + smashed.shape[2:])
         flat = _flat(batch)
@@ -265,10 +220,7 @@ class CPSL:
                   for k in range(Kc)]
             g_dev = jax.tree.map(lambda *ts: jnp.stack(ts), *gs)
         else:
-            with pt.exclude_axes(ax):
-                g_dev = jax.vmap(dev_bwd, spmd_axis_name=ax)(state["dev"],
-                                                             batch,
-                                                             g_smashed)
+            g_dev = jax.vmap(dev_bwd)(state["dev"], batch, g_smashed)
         with jax.named_scope("update"):
             new_dev, dev_opt = self.dev_opt.step(g_dev, state["dev_opt"],
                                                  state["dev"], state["step"],
@@ -329,8 +281,6 @@ class CPSL:
     def fedavg(self, state, data_sizes: Optional[jnp.ndarray] = None):
         """eq. (8): weights are the per-client local data sizes |D_{m,k}|
         (uniform when ``data_sizes`` is None)."""
-        if self.ccfg.share_device_params:
-            return state   # single shared device model: nothing to average
         K = self.ccfg.cluster_size
         w = (jnp.ones((K,), jnp.float32) if data_sizes is None
              else jnp.asarray(data_sizes, jnp.float32))
@@ -487,8 +437,7 @@ class CPSL:
                 st, mt = step_impl(st, batch, lr_scale=lr_scale)
                 st = jax.lax.optimization_barrier(st)
                 losses.append(mt["loss"])
-            if not self.ccfg.share_device_params:
-                st = jax.lax.optimization_barrier(self.fedavg_impl(st, w))
+            st = jax.lax.optimization_barrier(self.fedavg_impl(st, w))
             losses = jnp.stack(losses)
             if masked:
                 # padded cluster slot: discard the whole update (state,
@@ -679,7 +628,7 @@ class CPSL:
         ``eval_data``shared device-resident eval batch (not batched
                      over replicas).
 
-        Contract (tests/test_fleet.py, benchmarks/bench_fleet.py):
+        Contract (tests/test_fleet.py):
         replica r is bit-exact (ints/rng) and ULP-equal per leaf
         (floats) to the solo ``run_training_fused`` run with seed r at
         the same layout/lr. Masked (padded) slots never contribute:

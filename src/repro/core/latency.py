@@ -75,31 +75,6 @@ def cluster_latency(v: int, devices: Sequence[int], x: np.ndarray,
     return float(d_S + (L - 1) * d_I + d_E)          # D_m
 
 
-class BatchedClusterEvaluator:
-    """Vectorized ``cluster_latency`` for one fixed (cut layer, cluster,
-    network draw): the single-cluster (sizes=[K]) special case of
-    :class:`PartitionBatch` — one device row broadcast against whole
-    (P, K) batches of candidate allocations per call.
-
-    Exactness contract (inherited from ``PartitionBatch``, which keeps the
-    operand order of ``cluster_latency``): the evaluated latencies are
-    bit-identical to P scalar calls, so greedy/Gibbs *decisions* (argmins,
-    Metropolis accepts) made on top of them match the looped
-    implementations exactly. Tests assert this."""
-
-    def __init__(self, v: int, devices: Sequence[int], net: NetworkState,
-                 ncfg: NetworkCfg, prof: CutProfile, B: int, L: int,
-                 physical_gradients: bool = False):
-        dev = np.asarray(devices)
-        self._pb = PartitionBatch(v, net, ncfg, prof, B, L, [len(dev)],
-                                  dev[None, :],
-                                  physical_gradients=physical_gradients)
-
-    def latencies(self, xs: np.ndarray) -> np.ndarray:
-        """(P, K) candidate allocations -> (P,) cluster latencies D_m."""
-        return self._pb.latencies(xs)
-
-
 class PartitionBatch:
     """Replicated-partition evaluator: scores R *full* M-cluster partitions
     — optionally each under its own cut layer and network draw — in a
@@ -115,14 +90,14 @@ class PartitionBatch:
     Broadcasting applies: a single device row (1, N) may be scored against
     (P, N) candidate allocations and vice versa.
 
-    Exactness contract (same as ``BatchedClusterEvaluator``): every
-    expression keeps the operand order of ``cluster_latency``, all in
-    float64 — per-cluster latencies are bit-identical to scalar calls, and
-    totals accumulate clusters left-to-right so they are bit-identical to
-    the left-to-right totals of ``round_latency`` and
-    ``core.resource._round_latency_cached``. The multichain planner in
-    ``repro.sim.batched`` relies on this to keep chain 0 of its lockstep
-    Gibbs replicas bit-exact to the looped single-chain path."""
+    Exactness contract: every expression keeps the operand order of
+    ``cluster_latency``, all in float64 — per-cluster latencies are
+    bit-identical to scalar calls, and totals accumulate clusters
+    left-to-right so they are bit-identical to the left-to-right totals
+    of ``round_latency`` and ``core.resource._round_latency_cached``. The
+    multichain planner in ``repro.sim.batched`` relies on this to keep
+    chain 0 of its lockstep Gibbs replicas bit-exact to the looped
+    single-chain path."""
 
     def __init__(self, v, net: NetworkState, ncfg: NetworkCfg,
                  prof: CutProfile, B: int, L: int, sizes: Sequence[int],
@@ -206,19 +181,6 @@ class PartitionBatch:
         only plausible winners."""
         s, i, e = self.phase_terms(xs)
         return s + (self.L - 1) * i + e
-
-
-def cluster_latency_batch(v: int, devices: Sequence[int], xs: np.ndarray,
-                          net: NetworkState, ncfg: NetworkCfg,
-                          prof: CutProfile, B: int, L: int,
-                          physical_gradients: bool = False) -> np.ndarray:
-    """One-shot form of ``BatchedClusterEvaluator``: evaluate P candidate
-    allocations (``xs``: (P, K)) for a cluster, bit-identical to P scalar
-    ``cluster_latency`` calls. Build the evaluator directly when scoring
-    many batches for the same cluster."""
-    return BatchedClusterEvaluator(
-        v, devices, net, ncfg, prof, B, L,
-        physical_gradients=physical_gradients).latencies(xs)
 
 
 def equal_split_x(K: int, C: int) -> np.ndarray:

@@ -2,8 +2,6 @@
 
 ``CPSLDataset`` owns the non-IID device shards and yields batches shaped
 (K, B, ...) for the active cluster — the mini-batch draw of paper eq. (4).
-On a real multi-host pod each host would materialize only its mesh-row's
-clients; ``host_slice`` carries that logic (exercised logically here).
 
 ``DeviceResidentDataset`` is the fused-round mirror of ``CPSLDataset``:
 the full dataset is uploaded to the accelerator once, and each round the
@@ -32,7 +30,7 @@ from repro.streams import batch_seed  # re-export: the formula moved to
 
 __all__ = ["shard_sizes", "round_index_table", "batch_seed",
            "CPSLDataset", "DeviceResidentDataset", "FleetPlan",
-           "fleet_plan", "LMClusterData", "host_slice"]
+           "fleet_plan", "LMClusterData"]
 
 
 def shard_sizes(device_indices: List[np.ndarray],
@@ -277,15 +275,3 @@ class LMClusterData:
             parts = [self.lm.sample(self.B, self.S, self.rngs[d])
                      for d in devices]
         return {k: np.stack([p[k] for p in parts]) for k in parts[0]}
-
-
-def host_slice(batch: Dict[str, np.ndarray], host_id: int, n_hosts: int
-               ) -> Dict[str, np.ndarray]:
-    """Shard the client axis across hosts (multi-host data loading: each
-    host feeds only its addressable mesh rows)."""
-    def sl(t):
-        K = t.shape[0]
-        per = K // n_hosts
-        return t[host_id * per:(host_id + 1) * per]
-
-    return {k: sl(v) for k, v in batch.items()}

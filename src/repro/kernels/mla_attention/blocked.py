@@ -1,7 +1,7 @@
 """Blocked causal flash attention in jnp, at MLA's own widths.
 
-The CPU's path, and wherever the TPU kernel does not apply (a mesh that
-splits the call, a length no kernel block divides). Layout (B, S, H, D):
+The CPU's path, and wherever the TPU kernel does not apply (a length no
+kernel block divides). Layout (B, S, H, D):
 q and k carry the query-key width, v its own (DeepSeek-V2: 192 and 128).
 Semantics ``softmax(scale q k^T) v``, the scale applied to the float32
 scores, causal: key j is masked from query i when j > i.

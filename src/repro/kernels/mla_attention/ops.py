@@ -1,7 +1,7 @@
 """MLA's causal flash-attention core for training: one call, chosen by
 the backend and by what the call shows.
 
-On the TPU, outside a mesh, at a length the kernel's blocks divide: the
+On the TPU, at a length the kernel's blocks divide: the
 installed Pallas splash-attention kernels (a forward, a dq and a dkv
 kernel), which take v narrower than q and k and skip every block above
 the diagonal through their mask info. Elsewhere: ``blocked.flash``, the
@@ -15,7 +15,6 @@ import jax
 import jax.numpy as jnp
 
 from repro import kernels
-from repro.core import partitioning as pt
 from repro.kernels.mla_attention import blocked
 
 # The kernel's q and kv block, in every pass (tuned on a v5e chip at
@@ -24,8 +23,7 @@ KERNEL_BLOCK = 512
 
 
 def kernel_applies(S: int) -> bool:
-    return (jax.default_backend() == "tpu" and pt.active_mesh() is None
-            and S % KERNEL_BLOCK == 0)
+    return jax.default_backend() == "tpu" and S % KERNEL_BLOCK == 0
 
 
 def mla_blocks_computed(S: int, bq: int, bkv: int) -> int:
@@ -62,9 +60,4 @@ def mla_attention(q, k, v, *, scale: float, bq: int, bkv: int):
     S = q.shape[1]
     if kernel_applies(S):
         return splash_flash(q, k, v, scale)
-    # under a mesh, heads over its TP axis (batch-only where they don't
-    # divide it), as the grouped core lays them out
-    q = pt.shard(q, "batch", None, "heads", None)
-    k = pt.shard(k, "batch", None, "heads", None)
-    v = pt.shard(v, "batch", None, "heads", None)
     return blocked.flash(q, k, v, scale, bq, bkv)

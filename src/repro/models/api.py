@@ -33,12 +33,10 @@ def forward(params, batch: dict, cfg: ModelConfig):
     return tfm.forward(params, batch["tokens"], cfg)
 
 
-def prefill(params, batch: dict, cfg: ModelConfig, cap=None,
-            long_ctx: bool = False):
+def prefill(params, batch: dict, cfg: ModelConfig, cap=None):
     if cfg.encdec:
         return whp.prefill(params, batch, cfg, cap=cap)
-    return tfm.prefill(params, batch["tokens"], cfg, cap=cap,
-                       long_ctx=long_ctx)
+    return tfm.prefill(params, batch["tokens"], cfg, cap=cap)
 
 
 def decode_step(params, cache, tokens, pos, cfg: ModelConfig):

@@ -17,7 +17,6 @@ import jax.numpy as jnp
 from jax import lax
 
 from repro.configs.base import ModelConfig, MoECfg, MLACfg, YaRNCfg
-from repro.core import partitioning as pt
 from repro.kernels.mla_attention import ops as mla_ops
 
 Params = dict
@@ -323,40 +322,10 @@ def _flash_bwd_rule(causal, window, softcap, q_offset, q_chunk, kv_chunk,
 chunked_attention.defvjp(_flash_fwd_rule, _flash_bwd_rule)
 
 
-def shard_grouped_qkv(q, k, v):
-    """TP layout for the attention core: shard heads over 'model' where
-    divisible (kv-head group G first, else per-group R), otherwise fall
-    back to batch-only sharding — replicating heads beats contracting over
-    a sharded head_dim (which all-reduces every score tile)."""
-    hs = pt.axis_size("heads")
-    G, R = q.shape[2], q.shape[3]
-    if hs > 1 and G % hs == 0:
-        q = pt.shard(q, "batch", None, "heads", None, None)
-        k = pt.shard(k, "batch", None, "heads", None)
-        v = pt.shard(v, "batch", None, "heads", None)
-    elif hs > 1 and R % hs == 0:
-        q = pt.shard(q, "batch", None, None, "heads", None)
-        k = pt.shard(k, "batch", None, None, None)
-        v = pt.shard(v, "batch", None, None, None)
-    else:
-        # heads don't divide the TP axis (e.g. 14 heads on 16-way TP):
-        # replicate heads across TP, shard batch only. Wastes TP-axis
-        # compute on attention.
-        q = pt.shard(q, "batch", None, None, None, None)
-        k = pt.shard(k, "batch", None, None, None)
-        v = pt.shard(v, "batch", None, None, None)
-    return q, k, v
-
-
 def grouped_attention(q, k, v, cfg: ModelConfig, *, causal: bool,
                       window: int = 0, q_offset=0, kv_valid_len=None,
                       impl: Optional[str] = None) -> jnp.ndarray:
     impl = impl or cfg.attn_impl
-    if kv_valid_len is None and q.shape[1] > 1:
-        # full-seq self/cross attention: TP over heads. Decode paths keep
-        # the cache's (batch, kv_seq) layout — resharding a 32k cache
-        # every step would dwarf the step itself.
-        q, k, v = shard_grouped_qkv(q, k, v)
     # chunked/pallas need static q_offset (custom_vjp nondiff arg); traced
     # offsets only occur on decode/cache paths, which use naive anyway.
     fast_ok = (kv_valid_len is None and q.shape[1] > 1
@@ -436,16 +405,6 @@ def gqa_apply(p: Params, x: jnp.ndarray, cfg: ModelConfig, *,
         # only causal/window masking consults absolute positions
         q_offset = (positions[0] if (causal or window > 0)
                     and positions.ndim == 1 else 0)
-    # TP layout fix-up: when neither G nor R divides the TP axis but H
-    # does (qwen3: G=8, R=8, tp=16), flatten to per-head layout (G'=H,
-    # R'=1, kv broadcast) so heads shard cleanly. Per-device repeated-kv
-    # is S*(H/tp)*hd — no bigger than the unsharded grouped kv.
-    hs = pt.axis_size("heads")
-    if (kv is None and S > 1 and hs > 1 and G % hs and R % hs
-            and (G * R) % hs == 0):
-        k = jnp.repeat(k, R, axis=2)
-        v = jnp.repeat(v, R, axis=2)
-        q = q.reshape(B, S, G * R, 1, hd)
     o = grouped_attention(q, k, v, cfg, causal=causal, window=window,
                           q_offset=q_offset, kv_valid_len=kv_valid_len,
                           impl=impl)
@@ -540,7 +499,6 @@ def _mla(p, x, cfg, causal, positions, latent, kv_valid_len, absorbed):
         # grouped layout with G=1 kv head of width r+dr, value = c_kv (r)
         qq = qq.reshape(B, S, 1, H, r + dr) * (
             gain / math.sqrt((dn + dr) / (r + dr)))
-        qq = pt.shard(qq, "batch", None, None, "heads", None)
         kk = kk[:, :, None, :]
         vv = c_kv[:, :, None, :]
         o_lat = naive_attention(qq, kk, vv, causal=causal, q_offset=q_offset,
@@ -839,9 +797,8 @@ def lm_head_loss(head_w: jnp.ndarray, x: jnp.ndarray, labels: jnp.ndarray,
         logits = (x @ head_w.astype(x.dtype)).astype(jnp.float32)
         if cfg.final_softcap > 0:
             logits = _soft_cap(logits, cfg.final_softcap)
-        logits = pt.shard(logits, "batch", None, "vocab")
         return cross_entropy(logits, labels, mask)
-    # chunk along SEQ (keeps the (batch->data) sharding of every chunk)
+    # chunk along SEQ
     n = S // chunk
     mask = mask if mask is not None else jnp.ones((B, S), jnp.float32)
     return _fused_ce(x, head_w, labels, mask, n,
@@ -849,15 +806,10 @@ def lm_head_loss(head_w: jnp.ndarray, x: jnp.ndarray, labels: jnp.ndarray,
 
 
 def _ce_chunk_stats(xc, head_w, lc, softcap):
-    # CE-local layout: batch over 'data' only, vocab over 'model' — keeps
-    # logits AND the dW contraction vocab-sharded even under the fsdp
-    # profile (where 'model' otherwise belongs to the batch).
-    xc = pt.shard(xc, "ce_batch", None, None)
     logits = (xc @ head_w.astype(xc.dtype)).astype(jnp.float32)
     raw = logits
     if softcap > 0:
         logits = _soft_cap(logits, softcap)
-    logits = pt.shard(logits, "ce_batch", None, "ce_vocab")
     lse = jax.nn.logsumexp(logits, axis=-1)
     ll = jnp.take_along_axis(logits, lc[..., None], axis=-1)[..., 0]
     return logits, raw, lse, ll
@@ -870,8 +822,7 @@ def _fused_ce(x, head_w, labels, mask, n, softcap):
     AD through the chunk scan would (a) carry a full replicated f32
     (D, V) head-gradient accumulator and (b) all-gather the head per
     chunk. The custom backward recomputes each chunk's softmax, forms
-    dlogits = p - onehot, and accumulates dW with an explicit
-    (None, vocab) sharding constraint — dW stays vocab-sharded.
+    dlogits = p - onehot, and accumulates dW chunk by chunk.
     """
     return _fused_ce_fwd(x, head_w, labels, mask, n, softcap)[0]
 
@@ -912,16 +863,12 @@ def _fused_ce_bwd(n, softcap, res, g):
         dlogits = (p - onehot) * (mc * scale)[..., None]
         if softcap > 0:
             dlogits = dlogits * (1.0 - jnp.square(jnp.tanh(raw / softcap)))
-        dlogits = pt.shard(dlogits, "ce_batch", None, "ce_vocab")
         dxc = (dlogits @ head_w.astype(jnp.float32).T).astype(x.dtype)
-        dxc = pt.shard(dxc, "batch", None, None)
-        dW_c = jnp.einsum("bcd,bcv->dv",
-                          pt.shard(xc, "ce_batch", None, None)
-                          .astype(jnp.float32), dlogits)
-        dW = pt.shard(dW + dW_c, None, "ce_vocab")
+        dW = dW + jnp.einsum("bcd,bcv->dv", xc.astype(jnp.float32),
+                             dlogits)
         return dW, dxc
 
-    dW0 = pt.shard(jnp.zeros((D, V), jnp.float32), None, "ce_vocab")
+    dW0 = jnp.zeros((D, V), jnp.float32)
     dW, dxs = lax.scan(jax.checkpoint(body), dW0, (xr, lr, mr))
     dx = jnp.moveaxis(dxs, 0, 1).reshape(B, S, D)
     import numpy as _np

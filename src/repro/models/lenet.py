@@ -125,7 +125,7 @@ def _apply_layer(params, x, name, conv_impl="direct"):
         # 2x2/stride-2 max-pool as reshape + reduce-max: forward is
         # bit-identical to lax.reduce_window, but the gradient avoids
         # XLA:CPU's SelectAndScatter (a scalar loop, ~15x slower than the
-        # reduce-max transpose — measured in benchmarks/bench_round.py).
+        # reduce-max transpose, measured on XLA:CPU).
         # Tie-routing differs (reduce-max splits the cotangent among tied
         # maxima, e.g. ReLU zeros; SelectAndScatter picks the first) —
         # both are valid subgradients of max.

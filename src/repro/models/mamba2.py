@@ -21,7 +21,6 @@ from jax import lax
 from repro.configs.base import ModelConfig, SSMCfg
 from repro.models.common import (Params, dense, dense_init, norm_init,
                                  apply_norm, _normal, pdtype, cdtype)
-from repro.core import partitioning as pt
 
 
 # --------------------------------------------------------------------------
@@ -218,7 +217,6 @@ def mamba_apply(p: Params, x: jnp.ndarray, cfg: ModelConfig,
     xin, B_r, C_r = jnp.split(xbc, [d_inner, d_inner + s.ngroups * s.d_state],
                               axis=-1)
     xh = xin.reshape(B_, S, H, s.headdim)
-    xh = pt.shard(xh, "batch", None, "heads", None)
     Bh = _broadcast_groups(B_r, cfg)
     Ch = _broadcast_groups(C_r, cfg)
     dt = jax.nn.softplus(dtr.astype(jnp.float32)

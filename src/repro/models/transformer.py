@@ -3,8 +3,8 @@
 The stack = unrolled ``prologue`` blocks + ``lax.scan`` over ``n_periods``
 repetitions of ``pattern`` (params stacked on a leading axis). Scanning one
 *period* (e.g. gemma2's [local, global] pair or jamba's 8-layer unit) keeps
-the HLO compact — one traced period regardless of depth — which makes the
-512-way SPMD dry-run compiles fast.
+the HLO compact — one traced period regardless of depth — so compiles
+stay fast.
 """
 from __future__ import annotations
 
@@ -16,7 +16,6 @@ import jax.numpy as jnp
 from jax import lax
 
 from repro.configs.base import LayerSpec, ModelConfig
-from repro.core import partitioning as pt
 from repro.models import common as cm
 from repro.models import mamba2 as mb
 from repro.models.common import Params
@@ -69,7 +68,6 @@ def block_apply(p: Params, x, cfg: ModelConfig, spec: LayerSpec,
     if cfg.post_norm:
         a = cm.apply_norm(p["post_norm"], a, cfg.norm_kind, cfg.norm_eps)
     x = x + a
-    x = pt.shard(x, "batch", "seq", "embed")
     if spec.ffn != "none":
         h = cm.apply_norm(p["mlp_norm"], x, cfg.norm_kind, cfg.norm_eps)
         if spec.ffn == "moe":
@@ -80,7 +78,6 @@ def block_apply(p: Params, x, cfg: ModelConfig, spec: LayerSpec,
             f = cm.apply_norm(p["mlp_post_norm"], f, cfg.norm_kind,
                               cfg.norm_eps)
         x = x + f
-        x = pt.shard(x, "batch", "seq", "embed")
     return x, aux
 
 
@@ -88,41 +85,33 @@ def block_apply(p: Params, x, cfg: ModelConfig, spec: LayerSpec,
 # caches
 # --------------------------------------------------------------------------
 
-def _attn_cache_init(cfg: ModelConfig, batch: int, cap: int, long_ctx: bool):
+def _attn_cache_init(cfg: ModelConfig, batch: int, cap: int):
     dt = cm.cdtype(cfg)
-    seq_ax = "long_seq" if long_ctx else "kv_seq"
     if cfg.attn_kind == "mla":
         m = cfg.mla
         return {
-            "ckv": pt.shard(jnp.zeros((batch, cap, m.kv_lora_rank), dt),
-                            "batch", seq_ax, None),
-            "kr": pt.shard(jnp.zeros((batch, cap, m.qk_rope_head_dim), dt),
-                           "batch", seq_ax, None),
+            "ckv": jnp.zeros((batch, cap, m.kv_lora_rank), dt),
+            "kr": jnp.zeros((batch, cap, m.qk_rope_head_dim), dt),
         }
     hd, G = cfg.resolved_head_dim, cfg.n_kv_heads
     return {
-        "k": pt.shard(jnp.zeros((batch, cap, G, hd), dt),
-                      "batch", seq_ax, None, None),
-        "v": pt.shard(jnp.zeros((batch, cap, G, hd), dt),
-                      "batch", seq_ax, None, None),
+        "k": jnp.zeros((batch, cap, G, hd), dt),
+        "v": jnp.zeros((batch, cap, G, hd), dt),
     }
 
 
-def layer_cache_init(cfg: ModelConfig, spec: LayerSpec, batch: int, cap: int,
-                     long_ctx: bool = False):
+def layer_cache_init(cfg: ModelConfig, spec: LayerSpec, batch: int, cap: int):
     if spec.mixer == "attn":
-        return _attn_cache_init(cfg, batch, cap, long_ctx)
+        return _attn_cache_init(cfg, batch, cap)
     return mb.mamba_init_cache(cfg, batch, cm.cdtype(cfg))
 
 
-def init_cache(cfg: ModelConfig, batch: int, cap: int,
-               long_ctx: bool = False):
+def init_cache(cfg: ModelConfig, batch: int, cap: int):
     """Full-model cache: prologue list + per-pattern-position stacked."""
-    pro = [layer_cache_init(cfg, s, batch, cap, long_ctx)
-           for s in cfg.prologue]
+    pro = [layer_cache_init(cfg, s, batch, cap) for s in cfg.prologue]
     stack = []
     for s in cfg.pattern:
-        one = layer_cache_init(cfg, s, batch, cap, long_ctx)
+        one = layer_cache_init(cfg, s, batch, cap)
         stack.append(jax.tree.map(
             lambda t: jnp.broadcast_to(t[None], (cfg.n_periods,) + t.shape),
             one))
@@ -181,15 +170,14 @@ def block_decode(p: Params, x, cache, cfg: ModelConfig, spec: LayerSpec,
 
 
 def block_prefill(p: Params, x, cfg: ModelConfig, spec: LayerSpec,
-                  positions, cap: int, long_ctx: bool = False
-                  ) -> Tuple[jnp.ndarray, dict, dict]:
+                  positions, cap: int) -> Tuple[jnp.ndarray, dict, dict]:
     """Forward one block while building its decode cache. Returns
     (x, aux, cache). ``cap`` >= S is the cache capacity."""
     B, S, _ = x.shape
     aux = cm.aux_zero(cfg)
     h = cm.apply_norm(p["pre_norm"], x, cfg.norm_kind, cfg.norm_eps)
     if spec.mixer == "attn":
-        cache = _attn_cache_init(cfg, B, cap, long_ctx)
+        cache = _attn_cache_init(cfg, B, cap)
         if cfg.attn_kind == "mla":
             ckv, kr = cm.mla_project_latent(p["attn"], h, cfg, positions)
             cache["ckv"] = lax.dynamic_update_slice_in_dim(
@@ -298,11 +286,9 @@ def forward(params: Params, tokens: jnp.ndarray, cfg: ModelConfig,
         positions = jnp.arange(S)
     x = (cm.embed_apply(params["embed"], tokens, cfg)
          if inputs_embeds is None else inputs_embeds)
-    x = pt.shard(x, "batch", "seq", "embed")
     x, aux = _stack_forward(params, x, cfg, positions)
     x = cm.apply_norm(params["final_norm"], x, cfg.norm_kind, cfg.norm_eps)
     logits = cm.logits_apply(params["embed"], x, cfg)
-    logits = pt.shard(logits, "batch", "seq", "vocab")
     return logits, aux["loss"]
 
 
@@ -310,7 +296,6 @@ def final_hidden(params: Params, tokens: jnp.ndarray, cfg: ModelConfig):
     """Backbone up to (and incl.) the final norm. Returns (x, aux dict)."""
     positions = jnp.arange(tokens.shape[1])
     x = cm.embed_apply(params["embed"], tokens, cfg)
-    x = pt.shard(x, "batch", "seq", "embed")
     x, aux = _stack_forward(params, x, cfg, positions)
     x = cm.apply_norm(params["final_norm"], x, cfg.norm_kind, cfg.norm_eps)
     return x, aux
@@ -329,24 +314,23 @@ def loss_fn(params: Params, batch: dict, cfg: ModelConfig) -> jnp.ndarray:
 
 
 def prefill(params: Params, tokens: jnp.ndarray, cfg: ModelConfig,
-            cap: Optional[int] = None, long_ctx: bool = False):
+            cap: Optional[int] = None):
     """Forward + cache build. Returns (last-position logits, cache)."""
     B, S = tokens.shape
     cap = cap or S
     positions = jnp.arange(S)
     x = cm.embed_apply(params["embed"], tokens, cfg)
-    x = pt.shard(x, "batch", "seq", "embed")
     pro_caches = []
     for i, spec in enumerate(cfg.prologue):
         x, _, c = block_prefill(params["prologue"][i], x, cfg, spec,
-                                positions, cap, long_ctx)
+                                positions, cap)
         pro_caches.append(c)
 
     def body(x, period_params):
         caches = []
         for pos, spec in enumerate(cfg.pattern):
             x, _, c = block_prefill(period_params[pos], x, cfg, spec,
-                                    positions, cap, long_ctx)
+                                    positions, cap)
             caches.append(c)
         return x, tuple(caches)
 

@@ -1,7 +1,7 @@
 """Batched serving engine: prefill + decode with KV/SSM caches.
 
-``serve_step`` (one token for the whole batch) is the unit the decode-shape
-dry-runs lower. ``generate`` drives greedy/temperature sampling over a
+``serve_step`` (one token for the whole batch) is the decode unit.
+``generate`` drives greedy/temperature sampling over a
 fixed batch of requests (static shapes — continuous batching would swap
 finished rows; here rows finishing early keep decoding into padding, which
 is the shape-stable TPU-friendly variant).

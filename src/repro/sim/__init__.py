@@ -4,11 +4,8 @@ Layers on top of ``repro.core``:
   dynamics.py    Gauss-Markov correlated fading + compute drift, device
                  churn (arrival/departure) and per-device energy budgets —
                  generalizes the i.i.d. draws of ``core.channel``.
-  batched.py     vectorized candidate-allocation evaluation (bit-identical
-                 to the scalar ``core.latency.cluster_latency``) plus fast
-                 greedy/Gibbs built on it, and the replicated planner:
-                 lockstep multi-chain Gibbs + fully batched SAA over
-                 ``core.latency.PartitionBatch``.
+  batched.py     the replicated planner: lockstep multi-chain Gibbs and
+                 fully batched SAA over ``core.latency.PartitionBatch``.
   controller.py  online two-timescale controller wrapping Algs. 2-4 with a
                  stale-decision fallback for mid-round departures.
   engine.py      round executor coupling controller + latency model + the
@@ -20,10 +17,8 @@ Layers on top of ``repro.core``:
                  policies, and ``SimFleetRunner`` pricing a seeds x
                  policy x cluster-size x cut grid in one dispatch.
 """
-from repro.sim.batched import (BatchedClusterEvaluator, MultiChainResult,
-                               PartitionBatch, gibbs_clustering_batched,
+from repro.sim.batched import (MultiChainResult, PartitionBatch,
                                gibbs_clustering_multichain,
-                               greedy_spectrum_batched,
                                saa_cut_selection_batched)
 from repro.sim.controller import Plan, TwoTimescaleController
 from repro.sim.dynamics import DynamicsCfg, Event, NetworkProcess
@@ -32,8 +27,7 @@ from repro.sim.fleet import (PartitionBatchJ, SimFleetRunner,
                              fleet_trace_records, recompute_fleet_latencies)
 
 __all__ = [
-    "BatchedClusterEvaluator", "PartitionBatch", "MultiChainResult",
-    "greedy_spectrum_batched", "gibbs_clustering_batched",
+    "PartitionBatch", "MultiChainResult",
     "gibbs_clustering_multichain", "saa_cut_selection_batched",
     "Plan", "TwoTimescaleController",
     "DynamicsCfg", "Event", "NetworkProcess", "SimEngine",

@@ -1,16 +1,12 @@
-"""Vectorized + replicated candidate evaluation for Algs. 2/3/4.
+"""Replicated candidate evaluation for Algs. 2/3/4.
 
 The scalar reference ``core.resource.greedy_spectrum`` calls
-``cluster_latency`` once per candidate; ``core.resource.SpectrumTable``,
-which ``greedy_spectrum_batched`` below runs, prices the same candidates
-from a table of device phase terms. ``core.latency.BatchedClusterEvaluator``
-(re-exported here) hoists everything x-independent and scores whole
-(P, K) candidate batches with a handful of numpy broadcasts — with a
-bit-exactness contract to the scalar path, so the greedy/Gibbs
-*decisions* built on it below match the looped baselines exactly.
+``cluster_latency`` once per candidate; ``core.resource.SpectrumTable``
+prices the same candidates from a table of device phase terms, with
+identical decisions.
 
-On top of that sits the *replicated planner layer*
-(``core.latency.PartitionBatch``): R full M-cluster partitions — each
+The replicated planner layer builds on ``core.latency.PartitionBatch``:
+R full M-cluster partitions — each
 replica optionally under its own cut layer and network draw — are scored
 in a handful of broadcasts, which turns
 
@@ -40,34 +36,12 @@ import numpy as np
 from repro import streams
 from repro.core import resource as rs
 from repro.core.channel import NetworkCfg, NetworkState
-from repro.core.latency import (BatchedClusterEvaluator, CutProfile,
-                                PartitionBatch)
+from repro.core.latency import CutProfile, PartitionBatch
 
-__all__ = ["BatchedClusterEvaluator", "PartitionBatch",
-           "greedy_spectrum_batched", "gibbs_clustering_batched",
+__all__ = ["PartitionBatch",
            "saa_cut_selection_batched", "gibbs_clustering_multichain",
            "MultiChainResult", "hierarchical_gibbs_clustering",
            "HierarchicalResult"]
-
-
-def greedy_spectrum_batched(v: int, devices: Sequence[int],
-                            net: NetworkState, ncfg: NetworkCfg,
-                            prof: CutProfile, B: int, L: int,
-                            C: Optional[int] = None
-                            ) -> Tuple[np.ndarray, float]:
-    """Drop-in replacement for ``core.resource.greedy_spectrum`` with
-    identical decisions: Alg. 3 on a ``core.resource.SpectrumTable`` of
-    this call's K devices (bit-identical candidate latencies, same argmin
-    tie-breaks)."""
-    return rs.SpectrumTable(v, devices, net, ncfg, prof, B, L,
-                            C=C).greedy(devices)
-
-
-def gibbs_clustering_batched(*args, **kw):
-    """Alg. 4 with the vectorized Alg. 3 inner loop — same RNG stream and
-    same accepted swaps as ``core.resource.gibbs_clustering``."""
-    kw.setdefault("spectrum_fn", greedy_spectrum_batched)
-    return rs.gibbs_clustering(*args, **kw)
 
 
 # --------------------------------------------------------------------------

@@ -29,7 +29,6 @@ from repro.core import resource as rs
 from repro.core.channel import NetworkCfg, NetworkState
 from repro.core.latency import CutProfile, cluster_latency
 from repro.sim.batched import (gibbs_clustering_multichain,
-                               greedy_spectrum_batched,
                                hierarchical_gibbs_clustering,
                                saa_cut_selection_batched)
 
@@ -58,8 +57,13 @@ class Plan:
 
 
 class TwoTimescaleController:
+    """``spectrum_fn`` None runs the built-in Alg. 3 (a
+    ``core.resource.SpectrumTable``), which the replicated planners of
+    ``repro.sim.batched`` share; a custom one (e.g. the scalar reference
+    ``greedy_spectrum``) runs on the looped planners."""
+
     def __init__(self, prof: CutProfile, ncfg: NetworkCfg, B: int, L: int,
-                 scfg: SimCfg, spectrum_fn=greedy_spectrum_batched):
+                 scfg: SimCfg, spectrum_fn=None):
         self.prof, self.ncfg = prof, ncfg
         self.B, self.L = B, L
         self.scfg = scfg
@@ -105,7 +109,7 @@ class TwoTimescaleController:
             seed=self.scfg.seed + 7919 * slot + 104_729,
             cuts=self.scfg.cuts, means_override=(mu_f, mu_snr),
             sizes=sizes)
-        if self.spectrum_fn is greedy_spectrum_batched:
+        if self.spectrum_fn is None:
             v, means = saa_cut_selection_batched(
                 self.prof, self._ncfg_for(n), self.B, self.L,
                 chains=max(1, self.scfg.gibbs_chains), **kw)
@@ -142,8 +146,7 @@ class TwoTimescaleController:
                     rs.gibbs_clustering(
                         v, net, ncfg, self.prof, self.B, self.L,
                         n_clusters=len(sizes), cluster_size=max(sizes),
-                        sizes=sizes, draws=d,
-                        spectrum_fn=greedy_spectrum_batched)[2]
+                        sizes=sizes, draws=d)[2]
                     for d in gibbs[j])
                 tot += best
             means[ci] = tot / len(nets)
@@ -167,7 +170,7 @@ class TwoTimescaleController:
             results = [rs.gibbs_clustering(
                 self.v, net, self._ncfg_for(n), self.prof, self.B, self.L,
                 n_clusters=len(sizes), cluster_size=max(sizes),
-                sizes=sizes, draws=d, spectrum_fn=greedy_spectrum_batched)
+                sizes=sizes, draws=d)
                 for d in draws]
             clusters, xs, lat = results[int(np.argmin(
                 [r[2] for r in results]))]
@@ -179,7 +182,7 @@ class TwoTimescaleController:
         seed = self.scfg.seed + slot + 53_639
         chains = max(1, self.scfg.gibbs_chains)
         if (self.scfg.plan_mode == "bucketed"
-                and self.spectrum_fn is greedy_spectrum_batched):
+                and self.spectrum_fn is None):
             # population scale: per-bucket lockstep Gibbs stitched over
             # coarse (compute, channel) buckets. With n <= bucket_size
             # there is one bucket and the plan is bit-identical to the
@@ -190,7 +193,7 @@ class TwoTimescaleController:
                 seed=seed, chains=chains,
                 bucket_size=self.scfg.bucket_size,
                 spectrum_topk=self.scfg.spectrum_topk)
-        elif chains > 1 and self.spectrum_fn is greedy_spectrum_batched:
+        elif chains > 1 and self.spectrum_fn is None:
             # best-of-R lockstep chains; chain 0 is the single-chain
             # stream, so this only ever improves on the chains=1 plan
             clusters, xs, lat = gibbs_clustering_multichain(
@@ -243,9 +246,11 @@ class TwoTimescaleController:
                                       self.prof, self.B, self.L)
                 latency += lat
             else:
-                x2, lat = self.spectrum_fn(plan.v, keep, net,
-                                           self._ncfg_for(len(gid)),
-                                           self.prof, self.B, self.L)
+                args = (plan.v, keep, net, self._ncfg_for(len(gid)),
+                        self.prof, self.B, self.L)
+                x2, lat = (rs.SpectrumTable(*args).greedy(keep)
+                           if self.spectrum_fn is None
+                           else self.spectrum_fn(*args))
                 clusters.append(keep)
                 xs.append(x2)
                 latency += lat

@@ -45,7 +45,7 @@ churn uniforms, host ``round_latency`` pricing), and can couple a
 static-scenario grid to ``CPSL.run_fleet`` for joint latency x accuracy
 curves (``train_curves``).
 
-Equivalence contract (tests/test_simfleet.py, benchmarks/bench_simfleet):
+Equivalence contract (tests/test_simfleet.py):
 episode e's per-round latency trace matches the looped NumPy reference
 — and the ``recompute_trace_latencies`` oracle re-derivation from the
 traced (f, rate, clusters, xs, v) — to tight float64 tolerance, with
@@ -71,6 +71,7 @@ import numpy as np
 from repro import streams
 from repro.configs.base import SimFleetCfg
 from repro.core import latency as lt
+from repro.core import resource as rs
 from repro.core.channel import NetworkCfg, NetworkState, device_means
 # the jnp cost engine lives in core.latency since the population-scale
 # refactor; re-imported (and re-exported) here for the fleet program and
@@ -896,7 +897,6 @@ class SimFleetRunner:
         rules, host ``round_latency`` pricing (the proposed arm goes
         through the real ``TwoTimescaleController`` on its ``draws=``
         hooks). Returns SimEngine-style per-round records."""
-        from repro.sim.batched import greedy_spectrum_batched
 
         sp = self.specs[e]
         ncfg, prof, dcfg, fcfg = self.ncfg, self.prof, self.dcfg, self.fcfg
@@ -988,8 +988,8 @@ class SimFleetRunner:
                                 for m in range(len(sizes))]
                     for cl in clusters:
                         if policy == "greedy":
-                            x, _ = greedy_spectrum_batched(
-                                v_t, cl, net, ncfg, prof, B, L)
+                            x, _ = rs.SpectrumTable(
+                                v_t, cl, net, ncfg, prof, B, L).greedy(cl)
                         else:
                             x = equal_split_x(len(cl), C)
                         xs.append(np.asarray(x))
@@ -1017,8 +1017,8 @@ class SimFleetRunner:
                         kept_x.append(x)
                     else:
                         if policy in ("greedy", "proposed"):
-                            x2, _ = greedy_spectrum_batched(
-                                v_t, keep, net, ncfg, prof, B, L)
+                            x2, _ = rs.SpectrumTable(
+                                v_t, keep, net, ncfg, prof, B, L).greedy(keep)
                         else:
                             x2 = equal_split_x(len(keep), C)
                         kept_c.append(keep)

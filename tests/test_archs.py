@@ -1,6 +1,7 @@
 """Per-architecture smoke tests (deliverable f): every assigned arch at a
 REDUCED config runs forward + a CPSL train step on CPU with finite outputs
-and correct shapes. Full configs are exercised only by the dry-run."""
+and correct shapes. Full configs appear here only as abstract shapes
+(parameter counts)."""
 import jax
 import jax.numpy as jnp
 import pytest

@@ -1,6 +1,5 @@
 """Component-level units: MoE dispatch vs per-token oracle, SSD impls,
-MLA absorption, chunked CE, norms, optimizers, data pipeline,
-partitioning rules, HLO parser."""
+MLA absorption, chunked CE, norms, optimizers, data pipeline."""
 import dataclasses
 
 import jax
@@ -317,74 +316,3 @@ def test_markov_lm_learnable_structure():
     assert b["tokens"].shape == (4, 64)
     assert (b["tokens"] < 16).all()
     assert np.array_equal(b["tokens"][:, 1:], b["labels"][:, :-1])
-
-
-# -- HLO parser ---------------------------------------------------------------------
-
-HLO_FIXTURE = """
-HloModule test, entry_computation_layout={()->f32[]}
-
-%cond (p: (s32[], f32[8,8])) -> pred[] {
-  %p = (s32[], f32[8,8]{1,0}) parameter(0)
-  %c = s32[] constant(5)
-  %g = s32[] get-tuple-element(%p), index=0
-  ROOT %cmp = pred[] compare(%g, %c), direction=LT
-}
-
-%body (p: (s32[], f32[8,8])) -> (s32[], f32[8,8]) {
-  %p = (s32[], f32[8,8]{1,0}) parameter(0)
-  %g = f32[8,8]{1,0} get-tuple-element(%p), index=1
-  %w = f32[8,8]{1,0} all-reduce(%g), replica_groups={}, to_apply=%add
-  %d = f32[8,8]{1,0} dot(%g, %w), lhs_contracting_dims={1}, rhs_contracting_dims={0}
-  %i = s32[] get-tuple-element(%p), index=0
-  ROOT %t = (s32[], f32[8,8]{1,0}) tuple(%i, %d)
-}
-
-%add (a: f32[], b: f32[]) -> f32[] {
-  %a = f32[] parameter(0)
-  %b = f32[] parameter(1)
-  ROOT %s = f32[] add(%a, %b)
-}
-
-ENTRY %main () -> f32[] {
-  %init = (s32[], f32[8,8]{1,0}) tuple()
-  %wh = (s32[], f32[8,8]{1,0}) while(%init), condition=%cond, body=%body
-  %gg = f32[8,8]{1,0} get-tuple-element(%wh), index=1
-  ROOT %r = f32[] reduce(%gg), to_apply=%add
-}
-"""
-
-
-def test_hlo_parser_loop_multipliers():
-    from repro.launch.hlo_analysis import analyze
-    s = analyze(HLO_FIXTURE)
-    # dot: 2*8*8*8 = 1024 flops x 5 trips
-    assert s.flops == 1024 * 5
-    # all-reduce: 8*8*4 bytes x2 x 5 trips
-    assert s.coll["all-reduce"] == 8 * 8 * 4 * 2 * 5
-
-
-def test_param_rules_shapes():
-    from jax.sharding import PartitionSpec as P
-    from repro.core import partitioning as pt
-    params = {
-        "embed": {"tok": jax.ShapeDtypeStruct((64, 32), jnp.float32)},
-        "stack": [{"attn": {"wq": {"w": jax.ShapeDtypeStruct(
-            (4, 32, 64), jnp.float32)}}}],
-        "head": jax.ShapeDtypeStruct((32, 64), jnp.float32),
-    }
-
-    class FakeMesh:
-        axis_names = ("data", "model")
-        shape = {"data": 2, "model": 4}
-
-    pt._CTX.mesh = FakeMesh()
-    pt._CTX.rules = dict(pt.DEFAULT_RULES)
-    try:
-        specs = pt.param_specs(params)
-        assert specs["embed"]["tok"] == P("model", None)
-        assert specs["stack"][0]["attn"]["wq"]["w"] == P(None, "data",
-                                                         "model")
-        assert specs["head"] == P(None, "model")
-    finally:
-        pt._CTX.mesh = None

@@ -286,6 +286,29 @@ def test_padded_metrics_masked_and_replicas_track_solo():
                                    np.asarray(mf["loss"][e]), rtol=1e-4)
 
 
+def test_padded_layouts_share_one_executable():
+    """Cluster layouts of different sizes, padded by ``fleet_plan`` to
+    one (M, K), run through one compiled curve on the fleet lowering:
+    the first layout compiles, the others add no jit cache entry."""
+    from repro.analysis.jit_audit import CompileCounter
+    cp = _cpsl(_ccfg(n_clusters=3, cluster_size=6, local_epochs=1,
+                     conv_impl="im2col", scan_rounds=True,
+                     fused_round_unroll=1, unroll_clients=False))
+    dsd = _dsd()
+    for i, nm in enumerate((2, 3, 6)):       # 6 devices, K = nm
+        layout = [list(range(m * nm, (m + 1) * nm)) for m in range(6 // nm)]
+        plan = fleet_plan([_SHARDS], B, [layout], [0], R, 1, pad_to=(3, 6))
+        with CompileCounter(CPSL._run_training_fused,
+                            budget=1 if i == 0 else 0) as cc:
+            _, m = cp.run_training_fused(
+                cp.init_state(KEY), dsd.data, plan.idx[0], plan.weights[0],
+                eval_data=dsd.eval_data, eval_every=R,
+                cluster_mask=plan.cluster_mask[0],
+                client_mask=plan.client_mask[0])
+        assert cc.new_entries == (i == 0)
+        assert np.isfinite(np.asarray(m["loss"])).all()
+
+
 def test_fleet_plan_tables():
     """fleet_plan real rows == per-replica round tables (prefix-stable
     draws); padded slots zero-indexed with zero eq.-8 weight."""

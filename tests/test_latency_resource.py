@@ -142,7 +142,7 @@ def test_lenet_profile_matches_paper_smashed_size():
 def test_paper_round_latency_calibration():
     """§VIII-B: SL 13.90s, FL 33.43s, CPSL 3.78s. Our faithful formulas land
     within 30% (the paper's CPSL number appears to exclude per-round model
-    distribution/upload; see EXPERIMENTS.md)."""
+    distribution/upload)."""
     ncfg = NetworkCfg(homogeneous=True, f_sigma=0.0, snr_sigma_db=0.0)
     net = sample_network(ncfg, *device_means(ncfg, 0),
                          np.random.default_rng(0))
@@ -195,12 +195,13 @@ def test_greedy_early_exit_c_equals_k():
 
 @pytest.mark.parametrize("L,physical", [(1, False), (3, False), (2, True)])
 def test_cluster_latency_batch_matches_scalar(L, physical):
-    """Vectorized evaluator is bit-identical to scalar calls, elementwise."""
+    """``PartitionBatch`` with one cluster row scored against P candidate
+    allocations is bit-identical to scalar calls, elementwise."""
     net = _net(5, seed=9)
     rng = np.random.default_rng(0)
     xs = rng.integers(1, 9, size=(40, 5))
-    got = lt.cluster_latency_batch(1, list(range(5)), xs, net, NCFG, PROF,
-                                   16, L, physical_gradients=physical)
+    got = lt.PartitionBatch(1, net, NCFG, PROF, 16, L, [5], np.arange(5),
+                            physical_gradients=physical).latencies(xs)
     want = np.array([lt.cluster_latency(1, list(range(5)), x, net, NCFG,
                                         PROF, 16, L,
                                         physical_gradients=physical)
@@ -211,7 +212,8 @@ def test_cluster_latency_batch_matches_scalar(L, physical):
 def test_cluster_latency_batch_1d_input():
     net = _net(3, seed=4)
     x = np.array([2, 3, 4])
-    got = lt.cluster_latency_batch(1, [0, 1, 2], x, net, NCFG, PROF, 16, 1)
+    got = lt.PartitionBatch(1, net, NCFG, PROF, 16, 1, [3],
+                            np.arange(3)).latencies(x)
     assert got.shape == (1,)
     assert got[0] == lt.cluster_latency(1, [0, 1, 2], x, net, NCFG, PROF,
                                         16, 1)

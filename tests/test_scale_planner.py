@@ -10,7 +10,8 @@ fallbacks, each pinned here:
     opt-in agrees to ~1e-5 relative;
   * hierarchical two-level Gibbs (``hierarchical_gibbs_clustering``):
     a single bucket is bit-identical to ``gibbs_clustering_multichain``,
-    and multi-bucket solutions keep every partition/budget invariant.
+    and multi-bucket solutions keep every partition/budget invariant and
+    price within 2% of the flat plan.
 
 Plus the integration layers: ``SimCfg.plan_mode="bucketed"`` collapses
 to the flat plan when n <= bucket_size, and the episode fleet's
@@ -218,6 +219,28 @@ def test_hierarchical_chains_monotone():
                                           bucket_size=30)[2]
             for c in (1, 2, 4)]
     assert lats[1] <= lats[0] and lats[2] <= lats[1]
+
+
+@pytest.mark.parametrize("n", [100, 320])
+def test_hierarchical_plan_quality_vs_flat(n):
+    """At the paper's K=5, C=30: forced to one bucket, the hierarchical
+    plan prices exactly as the flat multichain plan; at buckets of 160
+    devices (two buckets at N=320, per-bucket iters 2 x bucket, flat
+    iters 2N) it prices within 2% of flat."""
+    K, B, L, chains = 5, 16, 1, 2
+    ncfg, net = _net(n, 0, c=30)
+    sizes = balanced_sizes(n, K)
+    flat = gibbs_clustering_multichain(
+        3, net, ncfg, PROF, B, L, len(sizes), max(sizes), iters=2 * n,
+        seed=0, chains=chains, sizes=sizes)[2]
+
+    def hier(bucket_size):
+        return hierarchical_gibbs_clustering(
+            3, net, ncfg, PROF, B, L, K, iters=2 * min(n, bucket_size),
+            seed=0, chains=chains, bucket_size=bucket_size)[2]
+
+    assert hier(n) == flat
+    assert hier(160) <= 1.02 * flat
 
 
 # --------------------------------------------------------------------------

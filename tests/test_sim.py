@@ -11,9 +11,6 @@ from repro.core import latency as lt
 from repro.core import profile as pf
 from repro.core import resource as rs
 from repro.core.channel import NetworkCfg, device_means, sample_network
-from repro.sim.batched import (BatchedClusterEvaluator,
-                               gibbs_clustering_batched,
-                               greedy_spectrum_batched)
 from repro.sim.controller import TwoTimescaleController, balanced_sizes
 from repro.sim.dynamics import DynamicsCfg, NetworkProcess
 from repro.sim.engine import SimEngine, recompute_trace_latencies
@@ -130,11 +127,11 @@ def test_energy_pinned_departure_carries_cause():
 
 def test_evaluator_bit_identical_to_scalar():
     net, ncfg = _net(5, seed=7)
-    ev = BatchedClusterEvaluator(1, list(range(5)), net, ncfg, PROF, 16, 2)
+    pb = lt.PartitionBatch(1, net, ncfg, PROF, 16, 2, [5], np.arange(5))
     xs = np.random.default_rng(0).integers(1, 7, size=(64, 5))
     want = np.array([lt.cluster_latency(1, list(range(5)), x, net, ncfg,
                                         PROF, 16, 2) for x in xs])
-    np.testing.assert_array_equal(ev.latencies(xs), want)
+    np.testing.assert_array_equal(pb.latencies(xs), want)
 
 
 @pytest.mark.parametrize("seed,K,L", [(0, 5, 1), (3, 5, 1), (17, 5, 1),
@@ -143,7 +140,7 @@ def test_batched_greedy_identical_decisions(seed, K, L):
     net, ncfg = _net(max(K, 5), seed=seed)
     args = (1, list(range(K)), net, ncfg, PROF, 16, L)
     xg, lg = rs.greedy_spectrum(*args)
-    xb, lb = greedy_spectrum_batched(*args)
+    xb, lb = rs.SpectrumTable(*args).greedy(list(range(K)))
     np.testing.assert_array_equal(xg, xb)
     assert lg == lb
 
@@ -151,9 +148,9 @@ def test_batched_greedy_identical_decisions(seed, K, L):
 def test_batched_gibbs_identical_decisions():
     net, ncfg = _net(12, seed=5)
     a = rs.gibbs_clustering(1, net, ncfg, PROF, 16, 1, 4, 3, iters=150,
+                            seed=2, spectrum_fn=rs.greedy_spectrum)
+    b = rs.gibbs_clustering(1, net, ncfg, PROF, 16, 1, 4, 3, iters=150,
                             seed=2)
-    b = gibbs_clustering_batched(1, net, ncfg, PROF, 16, 1, 4, 3, iters=150,
-                                 seed=2)
     assert a[0] == b[0] and a[2] == b[2]
     for x1, x2 in zip(a[1], b[1]):
         np.testing.assert_array_equal(x1, x2)

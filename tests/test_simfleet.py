@@ -63,8 +63,8 @@ def test_partition_batch_j_matches_numpy(seed, sizes):
 
 @pytest.mark.parametrize("physical", [False, True])
 def test_partition_batch_j_broadcast_and_scalar_cut(physical):
-    """Single device row scored against P candidate allocations (the
-    BatchedClusterEvaluator shape), scalar cut, physical_gradients."""
+    """Single device row scored against P candidate allocations, scalar
+    cut, physical_gradients."""
     rng = np.random.default_rng(7)
     ncfg = NetworkCfg(n_devices=5, n_subcarriers=10)
     net = sample_network(ncfg, *device_means(ncfg, 7), rng)
@@ -279,6 +279,11 @@ def _assert_decisions_match(runner, res, ref):
                 np.testing.assert_array_equal(a, b)
     want = recompute_fleet_latencies(res, PROF, runner.ncfg, 16, 1)
     np.testing.assert_allclose(res["trace"]["latency"], want, rtol=1e-12)
+    # every real cluster of every slot spends exactly the C budget
+    xs, mask = res["trace"]["xs"], res["trace"]["mask"]
+    sums = np.where(mask, xs, 0).sum(axis=-1)
+    assert (sums[res["trace"]["csize"] > 0]
+            == runner.ncfg.n_subcarriers).all()
 
 
 def test_proposed_arm_matches_host_controller():

@@ -38,6 +38,17 @@ def one_chip():
     return SingleDeviceSharding(topo.devices[0])
 
 
+@pytest.fixture(autouse=True)
+def cell_precision():
+    """Compile at JAX's default matmul precision, as the model cells run.
+    The setting is global: a test that ran earlier in the same process
+    may have left it at "highest" (the LeNet cell's), under which the
+    splash kernels' bfloat16 dots ask Mosaic for float32 contraction,
+    which it refuses ("Bad lhs type")."""
+    with jax.default_matmul_precision(None):
+        yield
+
+
 def _spec(sharding, shape, dtype=jnp.float32):
     return jax.ShapeDtypeStruct(shape, dtype, sharding=sharding)
 
